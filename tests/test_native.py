@@ -70,6 +70,25 @@ class TestNativeFastApply:
         assert mod is not None, "toolchain present; native module must build"
         assert hasattr(mod, "apply_job_tasks")
 
+    def test_loads_only_the_build_of_the_committed_source(self):
+        """The built module's path carries a digest of the C source, so a
+        module built from any other source is never the one loaded."""
+        import hashlib
+        import sysconfig
+
+        import volcano_tpu._native as native
+
+        native._reset()
+        mod = native.get_fastapply()
+        if mod is None:
+            pytest.skip("native module unavailable; fallback covered elsewhere")
+        src = os.path.join(os.path.dirname(native.__file__), "fastapply.c")
+        ext = sysconfig.get_config_var("EXT_SUFFIX")
+        with open(src, "rb") as f:
+            digest = hashlib.sha256(f.read() + ext.encode()).hexdigest()[:16]
+        assert mod.__file__ == os.path.join(
+            os.path.dirname(src), "build", digest, "_fastapply" + ext)
+
     def test_native_equals_python_oracle(self):
         """Same bindings, node accounting, and task statuses (session +
         cache trees) from the native loop and the Python loop."""

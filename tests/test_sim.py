@@ -586,7 +586,9 @@ class TestCli:
              "--seed", "4", "--duration", "8", "--quiet",
              "--repro-dir", str(tmp_path / "repro"),
              "--json", str(tmp_path / "summary.json")],
-            capture_output=True, text=True, timeout=240)
+            capture_output=True, text=True, timeout=240,
+            env=dict(os.environ,
+                     JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache")))
         assert out.returncode == 0, out.stderr[-2000:]
         tail = out.stdout.strip().splitlines()[-1]
         summary = json.loads(tail)

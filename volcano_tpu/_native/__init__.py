@@ -3,15 +3,17 @@
 The control plane is Python with the solve on TPU; the few remaining
 interpreted hot loops (the bulk-apply writeback, the per-operation
 preempt/reclaim transitions) have native equivalents here, compiled on
-demand with the system toolchain into this package directory and imported
-like any extension module. Every native path has a pure-Python fallback — a
+demand with the system toolchain into ``build/<source digest>/`` under this
+package directory and imported from there. Every native path has a pure-Python fallback — a
 missing compiler, failed build, or failed import degrades to the oracle
 implementation, never to an error.
 """
 
 from __future__ import annotations
 
-import importlib
+import functools
+import hashlib
+import importlib.util
 import logging
 import os
 import subprocess
@@ -41,35 +43,52 @@ def _lock(modname: str):
     return lk
 
 
+@functools.lru_cache(maxsize=None)
 def _paths(src: str, modname: str):
+    """(source, built module): the build lands under ``build/<digest>/``,
+    where the digest is of the committed C source and the interpreter's
+    extension suffix. A module built from any other source (a stale file
+    copied along with the tree) has another path and is never loaded."""
     ext = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
-    return os.path.join(_DIR, src), os.path.join(_DIR, modname + ext)
-
-
-def _is_fresh(src_path: str, out: str) -> bool:
-    return (os.path.exists(out)
-            and os.path.getmtime(out) >= os.path.getmtime(src_path))
+    src_path = os.path.join(_DIR, src)
+    with open(src_path, "rb") as f:
+        digest = hashlib.sha256(f.read() + ext.encode()).hexdigest()[:16]
+    return src_path, os.path.join(_DIR, "build", digest, modname + ext)
 
 
 def _build(src: str, modname: str) -> bool:
     src_path, out = _paths(src, modname)
-    if _is_fresh(src_path, out):
+    if os.path.exists(out):
         return True
+    os.makedirs(os.path.dirname(out), exist_ok=True)
+    tmp = f"{out}.{os.getpid()}.tmp"  # concurrent builders never share it
     cc = sysconfig.get_config_var("CC") or "cc"
     include = sysconfig.get_paths()["include"]
     cmd = [*cc.split(), "-O2", "-fPIC", "-shared",
-           f"-I{include}", src_path, "-o", out + ".tmp"]
+           f"-I{include}", src_path, "-o", tmp]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
-    except Exception as e:  # toolchain absent / sandboxed
+    except (OSError, subprocess.SubprocessError) as e:  # toolchain absent
         logger.info("native build unavailable (%s); using Python fallback", e)
         return False
     if proc.returncode != 0:
         logger.warning("native build failed; using Python fallback:\n%s",
                        proc.stderr[-2000:])
         return False
-    os.replace(out + ".tmp", out)
+    os.replace(tmp, out)
     return True
+
+
+def _load(src: str, modname: str):
+    _, out = _paths(src, modname)
+    mod = sys.modules.get(modname)
+    if mod is not None and getattr(mod, "__file__", None) == out:
+        return mod
+    spec = importlib.util.spec_from_file_location(modname, out)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    sys.modules[modname] = mod
+    return mod
 
 
 def _get(src: str, modname: str):
@@ -89,9 +108,7 @@ def _get(src: str, modname: str):
                 return None
             try:
                 if _build(src, modname):
-                    if _DIR not in sys.path:
-                        sys.path.insert(0, _DIR)
-                    st["mod"] = importlib.import_module(modname)
+                    st["mod"] = _load(src, modname)
             except Exception:
                 logger.exception(
                     "native %s unavailable; using Python fallback", modname)
@@ -112,8 +129,7 @@ def _get_nowait(src: str, modname: str):
         return st["mod"]
     if os.environ.get("VOLCANO_TPU_NO_NATIVE"):
         return None
-    src_path, out = _paths(src, modname)
-    if _is_fresh(src_path, out):
+    if os.path.exists(_paths(src, modname)[1]):
         return _get(src, modname)  # import only — no compiler run
     if st["thread"] is None:
         import threading

@@ -286,7 +286,7 @@ def _paper_2x(c: SchedulerCache, scale: float) -> int:
     """cfg7: the paper-2x standing config — 100k tasks x 50k nodes under
     the full default conf (ROADMAP item 3). Twice the paper's 50k x 10k
     north star on BOTH axes the mesh shards over, so the per-device-count
-    scaling curve (bench.py --mesh 1,2,4,8 -> tpu_mesh_curve) is measured
+    scaling curve (bench.py --mesh 1,2,4 -> tpu_mesh_curve) is measured
     against a cluster one chip cannot own: at 8 devices each shard still
     carries a cfg5-sized node slice."""
     rng = random.Random(7)

@@ -1112,7 +1112,7 @@ def _pack_staged(arrays: Dict[str, np.ndarray], tag: str, mesh,
 
 def _pack(arrays: Dict[str, np.ndarray], tag: str):
     """Concatenate host arrays into one flat buffer per dtype class (the
-    PJRT hop pays per buffer, not per byte) with a static unpack layout."""
+    host-device hop pays per buffer, not per byte) with a static unpack layout."""
     layout = []
     parts: Dict[str, list] = {}
     offsets: Dict[str, int] = {}

@@ -38,7 +38,7 @@ OWN single-device buffer, and untouched shards are not even dispatched
 to — the global array is reassembled without a copy
 (jax.make_array_from_single_device_arrays, the ops/shard.py idiom).
 
-Fallback taxonomy (``replica_rebuild{reason}``): any envelope miss
+Fallback kinds (``replica_rebuild{reason}``): any envelope miss
 restages wholesale and counts the reason — "cold" (first serve),
 "generation" (keeper wholesale invalidation), "shape"/"dtype" (padded
 extent or cast changed), "mesh" (device layout changed), "axis" (node

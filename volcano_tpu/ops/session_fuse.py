@@ -1,7 +1,6 @@
 """Whole-session fused dispatch: one device program chain per session.
 
-BENCH_r05 showed warm sessions are dispatch-bound, not compute-bound: the
-cfg4 overcommit chain pays four separate encode -> H2D -> dispatch ->
+Run per action, the cfg4 overcommit chain pays four separate encode -> H2D -> dispatch ->
 blocking-fetch -> host-apply round trips (allocate, backfill, preempt,
 reclaim), and each boundary re-encodes session state the PREVIOUS device
 stage already knew. This module fuses the remaining per-action boundary:

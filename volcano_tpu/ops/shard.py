@@ -1,8 +1,8 @@
 """Mesh sharding utilities: per-shard staging of the node axis.
 
-ROADMAP item 3 (MULTICHIP_r05): the node-axis shard of the rounds kernel
-is bit-identical to the single-device solve on an 8-device mesh, but the
-surrounding stages used to de-shard the axis — the encoder staged full-
+The node-axis shard of the rounds kernel is bit-identical to the
+single-device solve (tests/test_mesh_shard.py; chip_smoke.py --chips 4 on
+chips), but the surrounding stages used to de-shard the axis — the encoder staged full-
 width matrices through one `jax.device_put` stream per array (no device
 cache at all on the mesh path), and the evict victim folds ran unsharded.
 This module is the shared staging layer that keeps the axis sharded

@@ -61,6 +61,11 @@ class Counter:
         with self._lock:
             return self._data.get(labels, 0.0)
 
+    def total(self) -> float:
+        """Sum over every label set."""
+        with self._lock:
+            return sum(self._data.values())
+
 
 class Gauge:
     """A set-to-current-value metric (pending pods, queue depth): unlike a

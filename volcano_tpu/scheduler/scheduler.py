@@ -158,6 +158,7 @@ class Scheduler:
         # session gate share one ladder; embedders report remote-store
         # health through it too
         self.degrade = degrade_mod.default_ladder()
+        self.last_profile: dict = {}
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -357,5 +358,9 @@ class Scheduler:
             for name, ms in action_ms.items():
                 metrics.update_action_duration(name, ms / 1e3)
         finally:
+            tpu = ssn.plugins.get("tpuscore")
+            # the device path's record of this cycle (mode, fallbacks,
+            # timings), read by chip_smoke.py
+            self.last_profile = dict(tpu.profile) if tpu is not None else {}
             close_session(ssn)
         metrics.update_e2e_duration(time.perf_counter() - start)

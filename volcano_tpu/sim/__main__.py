@@ -77,4 +77,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from volcano_tpu.utils.jaxcompile import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

@@ -10,13 +10,26 @@ warm-path retrace shows up as `compiles > 0` in the BENCH record.
 
 Thread-safe for the single-writer / many-reader pattern JAX uses (listener
 callbacks fire on whichever thread compiles).
+
+`enable_compile_cache` places JAX's persistent compilation cache; the entry
+points (chip_smoke.py, bench.py, ``python -m volcano_tpu.scheduler``, the
+sim CLI) call it once at start-up, never at import time.
 """
 
 from __future__ import annotations
 
 import contextlib
+import os
 import threading
 from dataclasses import dataclass
+from typing import Mapping, Optional
+
+_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# fixed and inside the checkout (git-ignored): the cache key includes the
+# path, so a directory that moves between runs never hits
+_REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
 
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 _TRACE = "/jax/core/compile/jaxpr_trace_duration"
@@ -48,7 +61,7 @@ class CompileWatcher:
         with cls._lock:
             if cls._instance is None:
                 inst = cls()
-                from jax._src import monitoring
+                from jax import monitoring
 
                 def on_duration(event: str, duration: float, **kw) -> None:
                     if event == _BACKEND_COMPILE:
@@ -89,6 +102,32 @@ class CompileWatcher:
                 f"{d.traces} retrace(s)) inside a no-compile window — the "
                 f"session solve must stay ONE pre-compiled program "
                 f"(docs/static-analysis.md; BENCH tpu_warm_compiles)")
+
+
+def compile_cache_dir(environ: Mapping[str, str] = os.environ
+                      ) -> tuple[str, bool]:
+    """(directory, from_env): the directory JAX's persistent compilation
+    cache should use. ``JAX_COMPILATION_CACHE_DIR`` wins when set (JAX
+    reads it itself); otherwise ``<repo>/.jax_cache``."""
+    env = environ.get(_CACHE_ENV)
+    if env:
+        return env, True
+    return _REPO_CACHE_DIR, False
+
+
+def enable_compile_cache(environ: Optional[Mapping[str, str]] = None) -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, JAX already uses it and no
+    other directory is set here. Call once from an entry point, before the
+    first compile; never at import time."""
+    path, from_env = compile_cache_dir(
+        os.environ if environ is None else environ)
+    if not from_env:
+        import jax
+
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 class _Window:

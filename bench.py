@@ -261,9 +261,6 @@ def run_config(cfg: int, scale: float, backend: str, serial_budget: float,
                 "window_k": wp.get("window_k"),
                 "dirty_k": wp.get("dirty_k"),
                 "tail_placed": wp.get("tail_placed", 0),
-                "avg_round_ms": round(
-                    wp.get("dispatch_s", 0.0) * 1e3 / max(wp["rounds"], 1),
-                    3),
             }
         out["tpu_residue_ms"] = wp.get("residue_pass_ms", 0.0)
         out["tpu_residue_tasks"] = wp.get("residue_pass_tasks", 0)
@@ -322,7 +319,7 @@ def run_config(cfg: int, scale: float, backend: str, serial_budget: float,
             print(f"[cfg{cfg}] tpu warm e2e: {out['tpu_e2e_ms']:.1f} ms "
                   f"(open {out['tpu_open_ms']:.1f} actions {warm['actions_s']*1e3:.1f} "
                   f"close {out['tpu_close_ms']:.1f}) "
-                  f"(encode {p.get('encode_s', 0)*1e3:.1f} solve {p.get('solve_s', 0)*1e3:.1f} "
+                  f"(encode {p.get('encode_s', 0)*1e3:.1f} "
                   f"apply {p.get('apply_s', 0)*1e3:.1f}) binds={warm['binds']} "
                   f"actions={out['tpu_action_ms']} "
                   f"e2e_samples={[round(s) for s in e2e_samples]} compiles={warm_compiles}",
@@ -349,7 +346,7 @@ def run_mesh_curve(scale: float, counts, warm_iters: int = 2, cfg: int = 7):
 
     Runs in this process over the devices it has; a count larger than
     that is an error. Two figures per device count:
-    - ``warm_e2e_ms`` / ``solve_ms`` etc: the full warm session under that
+    - ``warm_e2e_ms`` / ``encode_ms`` etc: the full warm session under that
       mesh (on CPU virtual devices, which share one host, this column is
       structural — zero warm compiles, sharded staging engaged — not a
       parallel-speedup claim);
@@ -413,7 +410,6 @@ def run_mesh_curve(scale: float, counts, warm_iters: int = 2, cfg: int = 7):
             entry = {
                 "devices": d,
                 "warm_e2e_ms": round(statistics.median(e2e), 3),
-                "solve_ms": round(p.get("solve_s", 0.0) * 1e3, 3),
                 "encode_ms": round(p.get("encode_s", 0.0) * 1e3, 3),
                 "host_pack_ms": round(p.get("pack_s", 0.0) * 1e3, 3),
                 "h2d_ms": round(p.get("h2d_s", 0.0) * 1e3, 3),

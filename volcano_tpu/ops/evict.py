@@ -53,7 +53,6 @@ from __future__ import annotations
 import functools
 import logging
 import os
-import time
 from typing import Dict, List, NamedTuple, Optional
 
 import jax
@@ -68,6 +67,7 @@ from volcano_tpu.ops.solver import _bucket
 from volcano_tpu.scheduler import conf as conf_mod
 from volcano_tpu.scheduler.plugins import nodeorder as nodeorder_mod
 from volcano_tpu.scheduler.plugins.drf import SHARE_DELTA
+from volcano_tpu.utils import trace
 
 logger = logging.getLogger(__name__)
 
@@ -1095,18 +1095,22 @@ def _pack_staged(arrays: Dict[str, np.ndarray], tag: str, mesh,
     ride beside the packed groups under their plain names (merged back by
     rounds.unpack_layout, exactly like the solver's sharded encode)."""
     if mesh is None:
-        layout, bufs = _pack(arrays, tag)
-        return layout, _stage(bufs, profile)
+        with trace.span("pack", tag=tag):
+            layout, bufs = _pack(arrays, tag)
+        with trace.span("h2d", tag=tag):
+            return layout, _stage(bufs, profile)
     from volcano_tpu.ops import shard as shard_mod
 
-    d = shard_mod.device_count(mesh)
-    padded = pad_node_axis(arrays, d)
-    node = {k: padded[k] for k in _EV_NODE_AXIS if k in padded}
-    rest = {k: v for k, v in padded.items() if k not in node}
-    layout, bufs = _pack(rest, tag)
-    staged = _stage(bufs, profile, mesh=mesh)
-    staged.update(shard_mod.stage_node_arrays(
-        node, _EV_NODE_AXIS, mesh, profile, tag=f"ev.{tag}."))
+    with trace.span("pack", tag=tag):
+        d = shard_mod.device_count(mesh)
+        padded = pad_node_axis(arrays, d)
+        node = {k: padded[k] for k in _EV_NODE_AXIS if k in padded}
+        rest = {k: v for k, v in padded.items() if k not in node}
+        layout, bufs = _pack(rest, tag)
+    with trace.span("h2d", tag=tag):
+        staged = _stage(bufs, profile, mesh=mesh)
+        staged.update(shard_mod.stage_node_arrays(
+            node, _EV_NODE_AXIS, mesh, profile, tag=f"ev.{tag}."))
     return layout, staged
 
 
@@ -1282,9 +1286,10 @@ def build(ssn, kind: str):
     (the action then runs its old path — the parity oracle)."""
     prof = _profile(ssn)
     try:
-        if kind == "backfill":
-            return _BackfillPlan(ssn)
-        return _EvictPlan(ssn, kind)
+        with trace.span("evict.plan", kind=kind):
+            if kind == "backfill":
+                return _BackfillPlan(ssn)
+            return _EvictPlan(ssn, kind)
     except _Unsupported as e:
         reason = str(e)
         if reason in ("VOLCANO_TPU_EVICT=0", "tpuscore off"):
@@ -1314,7 +1319,6 @@ class _EvictPlan:
     def __init__(self, ssn, kind: str, fused: bool = False, view=None):
         from volcano_tpu.ops import encoder as enc_mod
 
-        t0 = time.perf_counter()
         self.ssn = ssn
         self.kind = kind
         self.fused = fused
@@ -1720,7 +1724,6 @@ class _EvictPlan:
         self.qnames = qnames
         self.t_real = t_real
         self.tb = tb
-        self.encode_s = time.perf_counter() - t0
 
     # -- run: dispatch once, fetch once, replay committed ops --------------
 
@@ -1732,15 +1735,15 @@ class _EvictPlan:
             return True
         from volcano_tpu.utils import devprof
 
-        t0 = time.perf_counter()
         layout, staged = _pack_staged(self.arrays, self.kind, self.mesh,
                                       prof)
         try:
             # async fetch (shared with the session-fused driver): the D2H
             # copy starts at dispatch and overlaps the host-side replay
             # scaffolding below; the wait is the action's one sync point
-            wait = devprof.start_fetch(
-                _solve_packed(self.spec, layout, staged))
+            with trace.span("dispatch", kind=self.kind):
+                wait = devprof.start_fetch(
+                    _solve_packed(self.spec, layout, staged))
             # host bookkeeping that needs no result: bind the replay
             # dependencies while the device still solves
             from volcano_tpu.scheduler import metrics  # noqa: F401
@@ -1753,10 +1756,9 @@ class _EvictPlan:
                              self.kind)
             _note_fallback(prof, key, f"solve error: {e}")
             return False
-        return self.consume(out, time.perf_counter() - t0)
+        return self.consume(out)
 
-    def consume(self, out: np.ndarray, solve_s: float,
-                kind: Optional[str] = None) -> bool:
+    def consume(self, out: np.ndarray, kind: Optional[str] = None) -> bool:
         """Validate + replay a fetched packed result (shared by run() and
         the session-fused driver — which replays BOTH evict stages through
         one fused-encode plan, passing ``kind`` explicitly). False =>
@@ -1765,7 +1767,14 @@ class _EvictPlan:
         kind = kind or self.kind
         prof = _profile(self.ssn)
         key = f"evict_{kind}"
-        t1 = time.perf_counter()
+        stage: dict = {}
+        with trace.span("evict.consume." + kind, into=(stage, "apply_s")):
+            ok = self._consume(out, kind, prof, key, stage)
+        if ok:
+            prof[key] = stage
+        return ok
+
+    def _consume(self, out, kind, prof, key, stage) -> bool:
         lr = self.log_rows
         tail = out[lr * 3:]
         log_len, rr, victims, attempts, fail, underflow = (
@@ -1787,11 +1796,7 @@ class _EvictPlan:
                 return False
         log = out[:log_len * 3].reshape(log_len, 3)
         self._replay(log, victims, attempts, rr, kind=kind)
-        prof[key] = {
-            "solve_s": solve_s, "apply_s": time.perf_counter() - t1,
-            "encode_s": self.encode_s, "ops": log_len,
-            "victims": victims, "attempts": attempts,
-        }
+        stage.update(ops=log_len, victims=victims, attempts=attempts)
         return True
 
     def _replay(self, log: np.ndarray, victims: int, attempts: int,
@@ -1852,7 +1857,6 @@ class _BackfillPlan:
     def __init__(self, ssn, view=None):
         from volcano_tpu.api import objects
 
-        t0 = time.perf_counter()
         self.ssn = ssn
         view = _common_view(ssn, view)
         self.view = view
@@ -1916,7 +1920,6 @@ class _BackfillPlan:
             check_pod_count=view.check_pod_count,
             use_nodeorder=False, use_binpack=False,
             use_gang_pipelined=False)
-        self.encode_s = time.perf_counter() - t0
 
     def run(self) -> bool:
         from volcano_tpu.api.unschedule_info import FitErrors, FitFailure
@@ -1929,12 +1932,12 @@ class _BackfillPlan:
         from volcano_tpu.utils import devprof
 
         ssn = self.ssn
-        t0 = time.perf_counter()
         layout, staged = _pack_staged(self.arrays, "backfill", self.mesh,
                                       prof)
         try:
-            wait = devprof.start_fetch(
-                _solve_packed(self.spec, layout, staged))
+            with trace.span("dispatch", kind="backfill"):
+                wait = devprof.start_fetch(
+                    _solve_packed(self.spec, layout, staged))
             # overlap the fetch with the replay's node-list build (the one
             # host-side O(N) term on this action's critical path)
             all_nodes = helper.get_node_list(ssn.nodes)
@@ -1943,19 +1946,24 @@ class _BackfillPlan:
             logger.exception("batched backfill solve failed; falling back")
             _note_fallback(prof, "evict_backfill", f"solve error: {e}")
             return False
-        return self.consume(assign, time.perf_counter() - t0,
-                            all_nodes=all_nodes)
+        return self.consume(assign, all_nodes=all_nodes)
 
-    def consume(self, assign: np.ndarray, solve_s: float,
-                all_nodes=None) -> bool:
+    def consume(self, assign: np.ndarray, all_nodes=None) -> bool:
         """Replay a fetched backfill assignment (shared by run() and the
         session-fused driver)."""
+        stage: dict = {}
+        with trace.span("evict.consume.backfill", into=(stage, "apply_s"),
+                        tasks=len(self.tasks)):
+            placed = self._consume(assign, all_nodes)
+        stage.update(tasks=len(self.tasks), placed=placed)
+        _profile(self.ssn)["evict_backfill"] = stage
+        return True
+
+    def _consume(self, assign: np.ndarray, all_nodes) -> int:
         from volcano_tpu.api.unschedule_info import FitErrors, FitFailure
         from volcano_tpu.scheduler.util import scheduler_helper as helper
 
         ssn = self.ssn
-        prof = _profile(ssn)
-        t1 = time.perf_counter()
         if all_nodes is None:
             all_nodes = helper.get_node_list(ssn.nodes)
         # budget for full per-node diagnostics replay on failures — same
@@ -2014,9 +2022,4 @@ class _BackfillPlan:
                     "%d feasible nodes rejected the backfill "
                     "allocation" % tried)
             job.nodes_fit_errors[task.uid] = fe
-        prof["evict_backfill"] = {
-            "solve_s": solve_s, "apply_s": time.perf_counter() - t1,
-            "encode_s": self.encode_s,
-            "tasks": len(self.tasks), "placed": placed,
-        }
-        return True
+        return placed

@@ -47,11 +47,12 @@ from __future__ import annotations
 import functools
 import logging
 import os
-import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
+
+from volcano_tpu.utils import trace
 
 logger = logging.getLogger(__name__)
 
@@ -366,24 +367,17 @@ def try_run(ssn, names) -> Optional[Dict[str, float]]:
         return None
     prefix, chain = split
 
-    from volcano_tpu.scheduler.framework import get_action
-
     action_ms: Dict[str, float] = {}
-    for name in prefix:
-        t0 = time.perf_counter()
-        get_action(name).execute(ssn)
-        action_ms[name] = round((time.perf_counter() - t0) * 1e3, 3)
+    _per_action(ssn, prefix, action_ms)
     _fuse_or_fallback(ssn, chain, action_ms)
     return action_ms
 
 
 def _per_action(ssn, names: List[str], action_ms: Dict[str, float]) -> None:
-    from volcano_tpu.scheduler.framework import get_action
+    from volcano_tpu.scheduler.framework.framework import run_action
 
     for name in names:
-        t0 = time.perf_counter()
-        get_action(name).execute(ssn)
-        action_ms[name] = round((time.perf_counter() - t0) * 1e3, 3)
+        run_action(ssn, name, action_ms)
 
 
 def _note_fuse_fallback(prof: dict, reason: str) -> None:
@@ -398,13 +392,46 @@ def _note_fuse_fallback(prof: dict, reason: str) -> None:
 def _fuse_or_fallback(ssn, chain: List[str],
                       action_ms: Dict[str, float]) -> None:
     """Attempt the fused chain; any envelope miss records `fuse_fallback`
-    and runs the (remaining) actions per-action."""
+    and runs the (remaining) actions per-action. The allocate action's
+    span holds the whole attempt: a declined or failed attempt runs the
+    plain allocate inside it, so allocate is timed once either way."""
+    from volcano_tpu.scheduler.framework.framework import action_span
+    from volcano_tpu.scheduler.framework.plugins import get_action
+
+    prof = ssn.batch_allocator.profile
+    with action_span("allocate", action_ms):
+        try:
+            fused = _fused_allocate(ssn, chain)
+        except Exception as e:  # pragma: no cover - device/compile failure
+            logger.exception("fused session dispatch failed; falling back")
+            _note_fuse_fallback(prof, f"fused dispatch error: {e}")
+            fused = None
+        if fused is None:
+            get_action("allocate").execute(ssn)
+    rest = [n for n in chain if n != "allocate"]
+    if fused is None or fused.get("invalid"):
+        _per_action(ssn, rest, action_ms)
+        return
+    try:
+        _replay_stages(ssn, chain, action_ms, fused)
+    except Exception as e:  # pragma: no cover - replay failure
+        logger.exception("fused stage replay failed; falling back")
+        _note_fuse_fallback(prof, f"fused dispatch error: {e}")
+        _per_action(ssn, [n for n in rest if n not in action_ms],
+                    action_ms)
+
+
+def _fused_allocate(ssn, chain: List[str]) -> Optional[dict]:
+    """Gate, encode and dispatch the whole chain, then apply the allocate
+    stage. None: the session left the envelope before anything was
+    applied (reason recorded). Otherwise the in-flight later stages, with
+    ``invalid`` set when the allocate stage's serial residue pass
+    invalidated them."""
     from volcano_tpu.ops import evict as evict_mod
 
     solver = ssn.batch_allocator
     prof = solver.profile
 
-    t_chain = time.perf_counter()
     prep = solver._prepare(ssn)
     if prep is None or prep["mode"] != "rounds" or prep["staged"] is None:
         # sub-threshold / unknown-plugin / encoder-fallback sessions run
@@ -412,8 +439,7 @@ def _fuse_or_fallback(ssn, chain: List[str],
         # _prepare already recorded the reason
         _note_fuse_fallback(prof, prof.get(
             "fallback", "allocate not in packed rounds mode"))
-        _per_action(ssn, chain, action_ms)
-        return
+        return None
     enc = prep["enc"]
     reason = None
     if enc.residue_count:
@@ -432,9 +458,10 @@ def _fuse_or_fallback(ssn, chain: List[str],
                  f"{sorted(set(ssn.job_valid_fns) - {'gang'})}"
     if reason is None:
         try:
-            plan = evict_mod._EvictPlan(ssn, "preempt", fused=True)
-            bf = evict_mod._BackfillPlan(ssn, view=plan.view) \
-                if "backfill" in chain else None
+            with trace.span("evict.plan", kind="fused"):
+                plan = evict_mod._EvictPlan(ssn, "preempt", fused=True)
+                bf = evict_mod._BackfillPlan(ssn, view=plan.view) \
+                    if "backfill" in chain else None
         except evict_mod._Unsupported as e:
             reason = str(e)
         else:
@@ -442,16 +469,8 @@ def _fuse_or_fallback(ssn, chain: List[str],
                 reason = "no pre-action preemptor candidates"
     if reason is not None:
         _note_fuse_fallback(prof, reason)
-        _per_action(ssn, chain, action_ms)
-        return
-
-    try:
-        _run_fused(ssn, chain, action_ms, prep, plan, bf, t_chain)
-    except Exception as e:  # pragma: no cover - device/compile failure
-        logger.exception("fused session dispatch failed; falling back")
-        _note_fuse_fallback(prof, f"fused dispatch error: {e}")
-        _per_action(ssn, [n for n in chain if n not in action_ms],
-                    action_ms)
+        return None
+    return _run_fused(ssn, chain, prep, plan, bf)
 
 
 def _build_maps(prep, plan, bf):
@@ -488,10 +507,11 @@ def _build_maps(prep, plan, bf):
     return maps, bmaps
 
 
-def _run_fused(ssn, chain, action_ms, prep, plan, bf, t_chain) -> None:
+def _run_fused(ssn, chain, prep, plan, bf) -> dict:
+    """Dispatch the whole chain, then apply the allocate stage while the
+    later stages still run; returns what ``_replay_stages`` needs."""
     from volcano_tpu.ops import evict as evict_mod
     from volcano_tpu.scheduler.actions import allocate as allocate_mod
-    from volcano_tpu.scheduler.framework import get_action
     from volcano_tpu.utils import devprof
 
     solver = ssn.batch_allocator
@@ -504,17 +524,21 @@ def _run_fused(ssn, chain, action_ms, prep, plan, bf, t_chain) -> None:
     # shipped per-shard beside the packed groups (the index MAPS stay
     # replicated — they are gathered by replicated task/assign vectors)
     mesh = solver.mesh
-    maps, bmaps = _build_maps(prep, plan, bf)
-    mlayout, mbufs = evict_mod._pack(maps, "fuse_maps")
-    mstaged = evict_mod._stage(mbufs, prof, mesh=mesh)
+    with trace.span("pack", tag="fuse_maps"):
+        maps, bmaps = _build_maps(prep, plan, bf)
+        mlayout, mbufs = evict_mod._pack(maps, "fuse_maps")
+    with trace.span("h2d", tag="fuse_maps"):
+        mstaged = evict_mod._stage(mbufs, prof, mesh=mesh)
     elayout, estaged = evict_mod._pack_staged(
         plan.arrays, "fuse_ev", mesh, prof)
     do_backfill = bf is not None and not bf.trivial
     if do_backfill:
         blayout, bstaged = evict_mod._pack_staged(
             bf.arrays, "fuse_bf", mesh, prof)
-        bml, bmb = evict_mod._pack(bmaps, "fuse_bmaps")
-        bmstaged = evict_mod._stage(bmb, prof, mesh=mesh)
+        with trace.span("pack", tag="fuse_bmaps"):
+            bml, bmb = evict_mod._pack(bmaps, "fuse_bmaps")
+        with trace.span("h2d", tag="fuse_bmaps"):
+            bmstaged = evict_mod._stage(bmb, prof, mesh=mesh)
 
     # jit-static stage sizes, all off the plan's bucket ladder (VT002)
     fs = plan.fuse_sizes
@@ -524,101 +548,99 @@ def _run_fused(ssn, chain, action_ms, prep, plan, bf, t_chain) -> None:
     use_gang_valid = "gang" in ssn.job_valid_fns
 
     # --- dispatch the whole chain eagerly (device-to-device carries) ------
-    t_disp = time.perf_counter()
-    packed_a, carry = _fuse_alloc(
-        prep["spec"], prep["layout"], prep["staged"],
-        mlayout, mstaged, sizes_a)
-    if do_backfill:
-        assign_bf, carry = _fuse_backfill(
-            bf.spec, blayout, bstaged, bml, bmstaged, carry)
-    packed_p, carry = _fuse_preempt(
-        plan.spec, elayout, estaged, carry, sizes_p)
-    # the adoption candidate is taken BEFORE any further donation: a
-    # reclaim stage consumes the carry (donate_argnums), so only a
-    # preempt-terminal chain has a live full-state carry left to adopt
-    adopt_carry = None if "reclaim" in chain else carry
-    if "reclaim" in chain:
-        packed_r = _fuse_reclaim(
-            plan.reclaim_spec, elayout, estaged, carry, sizes_r,
-            use_gang_valid)
-    # start every D2H copy now; waits below run in stage order while later
-    # stages still execute
-    wait_a = devprof.start_fetch(packed_a)
-    wait_bf = devprof.start_fetch(assign_bf) if do_backfill else None
-    wait_p = devprof.start_fetch(packed_p)
-    wait_r = devprof.start_fetch(packed_r) if "reclaim" in chain else None
-    prof["fuse_dispatch_s"] = time.perf_counter() - t_disp
+    with trace.span("dispatch", stages=len(chain)):
+        packed_a, carry = _fuse_alloc(
+            prep["spec"], prep["layout"], prep["staged"],
+            mlayout, mstaged, sizes_a)
+        if do_backfill:
+            assign_bf, carry = _fuse_backfill(
+                bf.spec, blayout, bstaged, bml, bmstaged, carry)
+        packed_p, carry = _fuse_preempt(
+            plan.spec, elayout, estaged, carry, sizes_p)
+        # the adoption candidate is taken BEFORE any further donation: a
+        # reclaim stage consumes the carry (donate_argnums), so only a
+        # preempt-terminal chain has a live full-state carry left to adopt
+        adopt_carry = None if "reclaim" in chain else carry
+        if "reclaim" in chain:
+            packed_r = _fuse_reclaim(
+                plan.reclaim_spec, elayout, estaged, carry, sizes_r,
+                use_gang_valid)
+        # start every D2H copy now; waits below run in stage order while
+        # later stages still execute
+        wait_a = devprof.start_fetch(packed_a)
+        wait_bf = devprof.start_fetch(assign_bf) if do_backfill else None
+        wait_p = devprof.start_fetch(packed_p)
+        wait_r = devprof.start_fetch(packed_r) if "reclaim" in chain \
+            else None
 
     # --- stage 1: allocate apply (overlaps the evict stages' compute) -----
     out_a = wait_a()
-    prof["pack_s"] = prep["pack_s"]
-    prof["h2d_s"] = prep["h2d_s"]
-    prof["dispatch_s"] = time.perf_counter() - t_disp
     assign, meta = solver.parse_packed(out_a)
     solver.apply_packed(ssn, prep, np.asarray(assign), meta)
     needs_residue = bool(prof.get("residue")) or (
         prof.get("has_releasing") and
         prof.get("tasks", 0) > prof.get("placed", 0))
     allocate_mod.finish_batched(ssn, solver)
-    action_ms["allocate"] = round(
-        (time.perf_counter() - t_chain) * 1e3, 3)
     if needs_residue:
         # the serial residue pass just mutated session state the remaining
         # device stages never saw: their results are invalid — discard
         # them and run the rest per-action (nothing else was applied)
         _note_fuse_fallback(prof, "allocate residue retry invalidated "
                                   "the fused evict stages")
-        _per_action(ssn, [n for n in chain if n != "allocate"], action_ms)
-        return
+        return {"invalid": True}
+    fused = dict(prep=prep, plan=plan, bf=bf, do_backfill=do_backfill,
+                 wait_bf=wait_bf, wait_p=wait_p, wait_r=wait_r)
+    # adopt_carry is None on every path where _fuse_reclaim donated the
+    # carry (both sides test the same '"reclaim" in chain'), so this alias
+    # only outlives a preempt-terminal chain:
+    # vclint: disable=VT012 - adopt_carry proven None when the carry was donated
+    fused["adopt_carry"] = adopt_carry
+    return fused
 
+
+def _replay_stages(ssn, chain, action_ms, fused: dict) -> None:
+    """Replay the backfill, preempt and reclaim stages' results in order,
+    each under its action's span."""
+    from volcano_tpu.scheduler.framework.framework import (
+        action_span, run_action)
+    from volcano_tpu.scheduler.framework.plugins import get_action
+
+    plan = fused["plan"]
     # --- stage 2: backfill replay ----------------------------------------
     if "backfill" in chain:
-        t0 = time.perf_counter()
-        if do_backfill:
-            bf.consume(wait_bf(), time.perf_counter() - t_disp)
-        else:
-            prof["evict_backfill"] = {"trivial": True}
-        action_ms["backfill"] = round((time.perf_counter() - t0) * 1e3, 3)
+        with action_span("backfill", action_ms):
+            if fused["do_backfill"]:
+                fused["bf"].consume(fused["wait_bf"]())
+            else:
+                ssn.batch_allocator.profile["evict_backfill"] = {
+                    "trivial": True}
 
     # --- stage 3: preempt op-log replay ----------------------------------
-    t0 = time.perf_counter()
-    out_p = wait_p()
-    ok = plan.consume(out_p, time.perf_counter() - t_disp, kind="preempt")
-    action_ms["preempt"] = round((time.perf_counter() - t0) * 1e3, 3)
+    with action_span("preempt", action_ms):
+        ok = plan.consume(fused["wait_p"](), kind="preempt")
+        if not ok:
+            # consume recorded the reason and applied nothing; the
+            # per-action rerun owns preempt AND reclaim (the fused reclaim
+            # consumed a carry whose preempt half never landed)
+            get_action("preempt").execute(ssn)
     if not ok:
-        # consume recorded the reason and applied nothing; the per-action
-        # rerun owns preempt AND reclaim (the fused reclaim consumed a
-        # carry whose preempt half never landed)
-        t0 = time.perf_counter()
-        get_action("preempt").execute(ssn)
-        action_ms["preempt"] = round((time.perf_counter() - t0) * 1e3, 3)
         if "reclaim" in chain:
-            t0 = time.perf_counter()
-            get_action("reclaim").execute(ssn)
-            action_ms["reclaim"] = round(
-                (time.perf_counter() - t0) * 1e3, 3)
+            run_action(ssn, "reclaim", action_ms)
         return
 
     # --- stage 4: reclaim op-log replay ----------------------------------
     if "reclaim" in chain:
-        t0 = time.perf_counter()
-        ok = plan.consume(wait_r(), time.perf_counter() - t_disp,
-                          kind="reclaim")
-        if not ok:
-            get_action("reclaim").execute(ssn)
-        action_ms["reclaim"] = round((time.perf_counter() - t0) * 1e3, 3)
-    elif ok and adopt_carry is not None:
+        with action_span("reclaim", action_ms):
+            if not plan.consume(fused["wait_r"](), kind="reclaim"):
+                get_action("reclaim").execute(ssn)
+    elif fused["adopt_carry"] is not None:
         # the chain ended at preempt, so its final carry was NOT donated
         # into a further stage: the post-chain node used/cnt it holds ARE
         # the cluster's next accounting state on device — hand them to the
         # standing replica instead of discarding them (ops/replica.py
         # adoption: the next serve skips re-scattering rows only this
         # chain's own placements changed)
-        # adopt_carry is None on every path where _fuse_reclaim donated
-        # the carry (both sides test the same '"reclaim" in chain'), so
-        # this alias only outlives a preempt-terminal chain:
-        # vclint: disable=VT012 - adopt_carry proven None when the carry was donated
-        _offer_carry(ssn, prep, plan, adopt_carry)
+        _offer_carry(ssn, fused["prep"], plan, fused["adopt_carry"])
 
 
 def _offer_carry(ssn, prep, plan, carry) -> None:

@@ -13,13 +13,13 @@ function of the snapshot (SURVEY.md §7 "hard parts").
 from __future__ import annotations
 
 import logging
-import time
 from typing import Dict, Optional
 
 import numpy as np
 
 from volcano_tpu.ops import kernels
 from volcano_tpu.ops.encoder import EncodedSnapshot, EncoderFallback, encode_session
+from volcano_tpu.utils import trace
 
 logger = logging.getLogger(__name__)
 
@@ -367,10 +367,35 @@ class BatchAllocator:
         fused driver (ops/session_fuse.py), which dispatches the same
         spec/layout/staged through its own chained program — or None after
         recording the fallback reason in the profile (the caller then runs
-        the serial loop)."""
+        the serial loop). The ``vt.encode``, ``vt.pack`` and ``vt.h2d``
+        spans feed the profile's ``encode_s``, ``pack_s`` and ``h2d_s``."""
         from volcano_tpu.scheduler import degrade
 
-        t0 = time.perf_counter()
+        with trace.span("encode", into=(self.profile, "encode_s")) as sp:
+            prep = self._encode(ssn)
+            if prep is not None:
+                t, n, j, *_ = prep["enc"].shape
+                sp.note(tasks=t, nodes=n, jobs=j)
+        if prep is None or prep["staged"] is not None:
+            return prep  # a fallback, or the reused bundle
+        rep, node_multiple = prep.pop("replica"), prep.pop("node_multiple")
+        if prep["mode"] != "rounds":
+            return prep  # parity mode runs unstaged
+        try:
+            self._stage_rounds(ssn, prep, rep, node_multiple)
+        except Exception as e:  # any device/compile failure -> serial oracle
+            logger.exception("tpuscore prepare failed; falling back to serial")
+            self.profile["fallback"] = f"solve error: {e}"
+            degrade.note_kernel_failure()
+            return None
+        return prep
+
+    def _encode(self, ssn):
+        """The host half of _prepare: None on a fallback (reason recorded),
+        the stored bundle when nothing the encode reads has moved, else a
+        fresh unstaged bundle."""
+        from volcano_tpu.scheduler import degrade
+
         if degrade.force_serial():
             # the kernel circuit breaker is OPEN (persistent device/compile
             # failure — the serial_host_solve rung): skip the doomed
@@ -407,13 +432,10 @@ class BatchAllocator:
 
         rep = replica_mod.get(getattr(ssn, "cache", None)) \
             if getattr(ssn, "cache", None) is not None else None
-        token = None
         if rep is not None:
-            token = rep.encode_token(ssn, self.mesh, self.mode)
-            prev = rep.serve_prepare(token)
+            prev = rep.serve_prepare(rep.encode_token(ssn, self.mesh,
+                                                      self.mode))
             if prev is not None:
-                prev["t0"] = t0
-                prev["t1"] = time.perf_counter()
                 self.profile["encode_reused"] = True
                 self.profile["h2d_puts"] = 0
                 self.profile["h2d_cached"] = 0
@@ -454,45 +476,18 @@ class BatchAllocator:
             if self.mesh is not None:
                 node_multiple = int(np.prod(list(self.mesh.shape.values())))
             arrays = self._cast(pad_encoded(enc, node_multiple))
-            if self.mesh is not None and mode != "rounds":
-                # parity mode keeps the per-array sharded puts (its
-                # sequential-scan kernel is strictly an oracle surface);
-                # rounds mode stages through the per-shard device cache
-                # below
-                arrays = self._shard(arrays)
-            t1 = time.perf_counter()
-            prep = dict(mode=mode, enc=enc, arrays=arrays, t0=t0, t1=t1,
-                        spec=None, layout=None, staged=None, pack_s=0.0,
-                        h2d_s=0.0,
-                        # host half of the read-set descriptor the pipeline
-                        # seals at speculative dispatch (the node half is
-                        # the kernel's touched mask, parse_packed): the job
-                        # uids the solve encoded, the queue/namespace ids
-                        # whose policy rows it consumed, and the
-                        # conservatism flag — residue/releasing sessions
-                        # run a serial pass over the whole snapshot at
-                        # apply, so the node read set degrades to the full
-                        # axis (driver side)
-                        readset=dict(
-                            job_uids=[j.uid for j in enc.job_infos],
-                            queue_ids=list(enc.queue_uids),
-                            ns_ids=list(enc.ns_names),
-                            read_all_nodes=bool(
-                                enc.residue_count or enc.has_releasing),
-                        ))
-
+            spec = None
             if mode == "rounds":
                 from volcano_tpu.ops import rounds as rounds_mod
 
-                rounds_arrays = {
-                    k: v for k, v in arrays.items() if k not in _ROUNDS_SKIP}
-                # diminishing-returns floor: keyed to the PADDED buckets so
-                # the spec (and the compiled program) stays stable across
-                # steady-state sessions of the same shape. Only worth it
-                # when the class axis spans multiple sweep chunks — those
-                # are the sessions whose fixed per-round cost dwarfs a few
-                # host-side residue placements; single-chunk rounds are
-                # cheaper than the serial pass they would shed
+                # diminishing-returns floor: keyed to the PADDED buckets
+                # so the spec (and the compiled program) stays stable
+                # across steady-state sessions of the same shape. Only
+                # worth it when the class axis spans multiple sweep
+                # chunks — those are the sessions whose fixed per-round
+                # cost dwarfs a few host-side residue placements;
+                # single-chunk rounds are cheaper than the serial pass
+                # they would shed
                 tb = int(np.asarray(arrays["task_cls"]).shape[0])
                 kb = int(np.asarray(arrays["cls_req"]).shape[0])
                 wf = _window_fields(arrays, shards=node_multiple)
@@ -505,68 +500,97 @@ class BatchAllocator:
                     # sweep) and typically halves the tail
                     straggler_rounds=4 if kb > rounds_mod.CHUNK else 0,
                     window_k=wf["window_k"], dirty_k=wf["dirty_k"])
-                prep["spec"] = spec
-                prep["arrays"] = rounds_arrays
-                # grouped packed transfer + device cache: unchanged groups
-                # never re-cross the host-device hop, and the solve
-                # returns ONE fetchable array (assign + rounds limbs) so
-                # the session pays a single D2H round trip. Under a mesh
-                # the node-axis arrays leave the pack and ride beside it
-                # as per-shard sharded buffers (ops/shard.py): unchanged
-                # shards stay device-resident, changed shards pay one put
-                # each — in parallel across the devices — and the merged
-                # dict feeds the SAME solve_rounds_packed entry (plain
-                # keys folded back in by rounds.unpack_layout)
-                # the state-dependent accounting arrays leave the pack and
-                # ride the standing device replica (ops/replica.py):
-                # committed deltas since the last session become bucketed
-                # row scatters against the persistent buffers instead of a
-                # host re-pack + device_put, and unpack_layout folds the
-                # plain-keyed replica buffers back in beside the packed
-                # groups exactly like the mesh path's sharded node arrays
-                rep_part = {}
-                if rep is not None:
-                    rep_part = {k: v for k, v in rounds_arrays.items()
-                                if k in replica_mod.SERVED}
-                if self.mesh is None:
-                    rest = {k: v for k, v in rounds_arrays.items()
-                            if k not in rep_part}
-                    layout, bufs = _pack(rest)
-                    t2 = time.perf_counter()
-                    staged = _stage(bufs, self.profile)
-                else:
-                    from volcano_tpu.ops import shard as shard_mod
-
-                    node_part = {k: rounds_arrays[k] for k in _NODE_AXIS
-                                 if k in rounds_arrays and k not in rep_part}
-                    rest = {k: v for k, v in rounds_arrays.items()
-                            if k not in node_part and k not in rep_part}
-                    layout, bufs = _pack(rest)
-                    t2 = time.perf_counter()
-                    staged = _stage(bufs, self.profile, mesh=self.mesh)
-                    staged.update(shard_mod.stage_node_arrays(
-                        node_part, _NODE_AXIS, self.mesh, self.profile))
-                    self.profile["mesh_devices"] = node_multiple
-                if rep_part:
-                    staged.update(rep.serve(
-                        rep_part, ssn, enc, self.mesh, self.profile))
-                prep["layout"] = layout
-                prep["staged"] = staged
-                prep["pack_s"] = t2 - t1
-                prep["h2d_s"] = time.perf_counter() - t2
-                if rep is not None:
-                    # token recomputed AFTER the serve: the serve bumps the
-                    # replica epoch (a fingerprint component), and the
-                    # stored token must describe the state this bundle was
-                    # built against so an unchanged next session hits
-                    rep.store_prepare(
-                        rep.encode_token(ssn, self.mesh, self.mode), prep)
+                arrays = {k: v for k, v in arrays.items()
+                          if k not in _ROUNDS_SKIP}
+            elif self.mesh is not None:
+                # parity mode keeps the per-array sharded puts (its
+                # sequential-scan kernel is strictly an oracle surface);
+                # rounds mode stages through the per-shard device cache
+                # (_stage_rounds)
+                arrays = self._shard(arrays)
         except Exception as e:  # any device/compile failure -> serial oracle
             logger.exception("tpuscore prepare failed; falling back to serial")
             self.profile["fallback"] = f"solve error: {e}"
             degrade.note_kernel_failure()
             return None
-        return prep
+        return dict(mode=mode, enc=enc, arrays=arrays, spec=spec,
+                    layout=None, staged=None,
+                    node_multiple=node_multiple, replica=rep,
+                    # host half of the read-set descriptor the pipeline
+                    # seals at speculative dispatch (the node half is
+                    # the kernel's touched mask, parse_packed): the job
+                    # uids the solve encoded, the queue/namespace ids
+                    # whose policy rows it consumed, and the
+                    # conservatism flag — residue/releasing sessions
+                    # run a serial pass over the whole snapshot at
+                    # apply, so the node read set degrades to the full
+                    # axis (driver side)
+                    readset=dict(
+                        job_uids=[j.uid for j in enc.job_infos],
+                        queue_ids=list(enc.queue_uids),
+                        ns_ids=list(enc.ns_names),
+                        read_all_nodes=bool(
+                            enc.residue_count or enc.has_releasing),
+                    ))
+
+    def _stage_rounds(self, ssn, prep: dict, rep, node_multiple: int
+                      ) -> None:
+        """Rounds mode: grouped pack and device staging of a fresh bundle,
+        in place; the bundle is then stored for whole-encode reuse."""
+        from volcano_tpu.ops import replica as replica_mod
+
+        enc, arrays = prep["enc"], prep["arrays"]
+        with trace.span("pack", into=(self.profile, "pack_s")):
+            # grouped packed transfer + device cache: unchanged groups
+            # never re-cross the host-device hop, and the solve
+            # returns ONE fetchable array (assign + rounds limbs) so
+            # the session pays a single D2H round trip. Under a mesh
+            # the node-axis arrays leave the pack and ride beside it
+            # as per-shard sharded buffers (ops/shard.py): unchanged
+            # shards stay device-resident, changed shards pay one put
+            # each — in parallel across the devices — and the merged
+            # dict feeds the SAME solve_rounds_packed entry (plain
+            # keys folded back in by rounds.unpack_layout)
+            # the state-dependent accounting arrays leave the pack and
+            # ride the standing device replica (ops/replica.py):
+            # committed deltas since the last session become bucketed
+            # row scatters against the persistent buffers instead of a
+            # host re-pack + device_put, and unpack_layout folds the
+            # plain-keyed replica buffers back in beside the packed
+            # groups exactly like the mesh path's sharded node arrays
+            rep_part = {}
+            if rep is not None:
+                rep_part = {k: v for k, v in arrays.items()
+                            if k in replica_mod.SERVED}
+            node_part = {}
+            if self.mesh is not None:
+                node_part = {k: arrays[k] for k in _NODE_AXIS
+                             if k in arrays and k not in rep_part}
+            rest = {k: v for k, v in arrays.items()
+                    if k not in node_part and k not in rep_part}
+            prep["layout"], bufs = _pack(rest)
+        with trace.span("h2d", into=(self.profile, "h2d_s")) as sp:
+            staged = _stage(bufs, self.profile, mesh=self.mesh)
+            if self.mesh is not None:
+                from volcano_tpu.ops import shard as shard_mod
+
+                staged.update(shard_mod.stage_node_arrays(
+                    node_part, _NODE_AXIS, self.mesh, self.profile))
+                self.profile["mesh_devices"] = node_multiple
+            if rep_part:
+                staged.update(rep.serve(
+                    rep_part, ssn, enc, self.mesh, self.profile))
+            sp.note(h2d_bytes=self.profile.get("h2d_bytes", 0))
+        prep["staged"] = staged
+        if rep is not None:
+            # token recomputed AFTER the serve: the serve bumps the
+            # replica epoch (a fingerprint component), and the
+            # stored token must describe the state this bundle was
+            # built against so an unchanged next session hits. The
+            # store releases the previous session's bundle.
+            with trace.span("replica.store"):
+                rep.store_prepare(
+                    rep.encode_token(ssn, self.mesh, self.mode), prep)
 
     def parse_packed(self, out: np.ndarray):
         """Split the packed single-fetch result into (assign, meta dict)."""
@@ -617,20 +641,24 @@ class BatchAllocator:
         # treat as an upper bound on tail contribution, not a net
         # figure
         self.profile["tail_placed"] = meta["tail_placed"]
-        t2 = time.perf_counter()
         self.profile["mode"] = "rounds"
-        self._apply_bulk(ssn, enc, assign)
-        t3 = time.perf_counter()
+        self._applied(ssn, enc, assign, self._apply_bulk)
+        return True
+
+    def _applied(self, ssn, enc: EncodedSnapshot, assign: np.ndarray,
+                 apply_fn) -> None:
+        """Write the placements back under the ``vt.apply`` span (the
+        profile's ``apply_s``) and record the session's solve counts."""
         t, n, j, *_ = enc.shape
+        placed = int((assign[: len(enc.task_infos)] >= 0).sum())
+        with trace.span("apply", into=(self.profile, "apply_s"),
+                        binds=placed):
+            apply_fn(ssn, enc, assign)
         self.profile.update(
-            encode_s=prep["t1"] - prep["t0"], solve_s=t2 - prep["t1"],
-            apply_s=t3 - t2,
-            tasks=t, nodes=n, jobs=j,
-            placed=int((assign[: len(enc.task_infos)] >= 0).sum()),
+            tasks=t, nodes=n, jobs=j, placed=placed,
             residue=enc.residue_count,
             has_releasing=enc.has_releasing,
         )
-        return True
 
     def __call__(self, ssn) -> bool:
         from volcano_tpu.scheduler.util import scheduler_helper
@@ -641,12 +669,10 @@ class BatchAllocator:
             return False
         mode = prep["mode"]
         enc = prep["enc"]
-        t1 = prep["t1"]
         try:
             if mode == "rounds":
                 from volcano_tpu.ops import rounds as rounds_mod
 
-                tp = time.perf_counter()
                 # async fetch: the copy starts at dispatch, and the
                 # wait is the session's counted sync point (devprof).
                 # One entry serves both layouts: under a mesh the staged
@@ -654,20 +680,19 @@ class BatchAllocator:
                 # groups (unpack_layout merges them), so the sharded
                 # session is byte-for-byte the single-device program over
                 # identical values
-                wait = devprof.start_fetch(rounds_mod.solve_rounds_packed(
-                    prep["spec"], prep["layout"], prep["staged"]))
+                with trace.span("dispatch"):
+                    wait = devprof.start_fetch(rounds_mod.solve_rounds_packed(
+                        prep["spec"], prep["layout"], prep["staged"]))
                 out = wait()
-                self.profile["pack_s"] = prep["pack_s"]
-                self.profile["h2d_s"] = prep["h2d_s"]
-                self.profile["dispatch_s"] = time.perf_counter() - tp
                 assign, meta = self.parse_packed(out)
                 assign = np.asarray(assign)
             else:
-                assign, rr = kernels.solve_allocate(
-                    enc.spec, prep["arrays"], np.int32(enc.rr0),
-                    np.int32(enc.num_to_find)
-                )
-                assign = np.asarray(assign)
+                with trace.span("dispatch", mode=mode):
+                    assign, rr = kernels.solve_allocate(
+                        enc.spec, prep["arrays"], np.int32(enc.rr0),
+                        np.int32(enc.num_to_find)
+                    )
+                    assign = np.asarray(assign)
                 # round-robin index continues across sessions exactly like
                 # the serial helper (scheduler_helper.go:38)
                 scheduler_helper._last_processed_node_index = int(rr)
@@ -684,18 +709,8 @@ class BatchAllocator:
 
         if mode == "rounds":
             return self.apply_packed(ssn, prep, assign, meta)
-        t2 = time.perf_counter()
         self.profile["mode"] = mode
-        self._apply(ssn, enc, assign)
-        t3 = time.perf_counter()
-        t, n, j, *_ = enc.shape
-        self.profile.update(
-            encode_s=t1 - prep["t0"], solve_s=t2 - t1, apply_s=t3 - t2,
-            tasks=t, nodes=n, jobs=j,
-            placed=int((assign[: len(enc.task_infos)] >= 0).sum()),
-            residue=enc.residue_count,
-            has_releasing=enc.has_releasing,
-        )
+        self._applied(ssn, enc, assign, self._apply)
         return True
 
     def _apply(self, ssn, enc: EncodedSnapshot, assign: np.ndarray) -> None:
@@ -765,450 +780,445 @@ class BatchAllocator:
         from volcano_tpu.scheduler.cache.interface import BindManyError
 
         ssn._placement_gen += 1
-        prof_t0 = time.perf_counter()
-        a = enc.arrays
-        t_real = len(enc.task_infos)
-        assign = assign[:t_real]
-        capped = assign == -2
-        if capped.any():
-            # diminishing-returns leftovers (rounds.py capped exit) fold
-            # into residue accounting: the serial pass retries exactly
-            # these tasks, and the fit-error stamping below skips their
-            # jobs — no stale '0/N nodes' error outlives the retry
-            cap_counts = np.bincount(
-                a["task_job"][:t_real][capped],
-                minlength=len(enc.job_infos)).astype(np.int32)
-            if enc.job_residue is None:
-                enc.job_residue = cap_counts
-            else:
-                enc.job_residue = enc.job_residue + cap_counts
-            enc.residue_count += int(capped.sum())
-            self.profile["round_capped_tasks"] = int(capped.sum())
-            assign = np.where(capped, np.int32(-1), assign)
-        placed_mask = assign >= 0
-
-        # --- vectorized per-node / per-job resource deltas ----------------
-        node_ids = assign[placed_mask]
-        reqs = a["task_req"][:t_real][placed_mask]
-        n_count = len(enc.node_names)
-        j_count = len(enc.job_infos)
-        sums = np.zeros((n_count, reqs.shape[1]))
-        np.add.at(sums, node_ids, reqs)
-        counts = np.bincount(node_ids, minlength=n_count)
-        job_ids = a["task_job"][:t_real][placed_mask]
-        job_sums = np.zeros((j_count, reqs.shape[1]))
-        np.add.at(job_sums, job_ids, reqs)
-        job_placed_n = np.bincount(job_ids, minlength=j_count)
-
-        # resource dim names recovered from the encoder's layout
-        scalar_names = enc.resource_names[2:]
-
-        def apply_delta(res: Resource, vec, sign: float) -> None:
-            res.milli_cpu += sign * vec[0]
-            res.memory += sign * vec[1]
-            for si, name in enumerate(scalar_names):
-                q = vec[2 + si]
-                if q:
-                    res.add_scalar(name, sign * q)
-
-        BINDING = TaskStatus.BINDING
-        PENDING = TaskStatus.PENDING
-        task_infos = enc.task_infos
-        job_infos = enc.job_infos
-        node_names = enc.node_names
-        cache = ssn.cache
-        ssn_nodes = ssn.nodes
-        cache_nodes = cache.nodes
-        vb = cache.volume_binder
-        # volume calls are skippable when the binder is a declared no-op
-        # OR no pod in the cache references a PVC (counter maintained by
-        # the cache's task handlers) — a real StoreVolumeBinder then costs
-        # nothing on PVC-free sessions and the native loop stays eligible
-        vols_noop = getattr(vb, "IS_NOOP", False) or (
-            getattr(cache, "_pvc_pod_count", 1) == 0)
-        alloc_vols = vb.allocate_volumes
-        bind_vols = vb.bind_volumes
-
-        placed_arr = np.nonzero(placed_mask)[0]
-        job_nz_arr = np.nonzero(job_placed_n)[0]
-        seg_ends_arr = np.cumsum(job_placed_n[job_nz_arr])
-        job_nz = job_nz_arr.tolist()
-
-        # tasks are contiguous per job on the flat axis, so placed visits
-        # each job's placements as one contiguous run. The loop allocates
-        # ~1 object + a few dict entries per task; suppress the cyclic GC so
-        # gen-promotion scans of the (multi-million-object) session heap
-        # don't fire mid-apply.
-        import gc
-
-        self.profile["apply_prep_s"] = time.perf_counter() - prof_t0
-        prof_t1 = time.perf_counter()
-        gc_was = gc.isenabled()
-        gc.disable()
-        bind_tasks: list = []
-        bind_pods: list = []
-        bind_hosts: list = []
-        bind_keys: list = []
-        # native batched loop (volcano_tpu/_native/fastapply.c): identical
-        # semantics to the Python body below, which remains the fallback
-        # and oracle; volumes force the Python path (effector calls)
-        # non-blocking: a cold process compiles on a background thread
-        # and THIS session runs the Python loop; never wait on cc here
-        from volcano_tpu._native import get_fastapply_nowait
-
-        mod = get_fastapply_nowait()
-        fast_all = getattr(mod, "apply_all_jobs", None) \
-            if (mod is not None and vols_noop) else None
-        # a keyed binder that declares it does not consume pod objects
-        # (KEYED_NEEDS_PODS = False — the k8s Bind subresource needs only
-        # name + target) lets the writeback skip 50k .pod extractions;
-        # the BindManyError retry path still reads task.pod lazily
-        binder0 = cache.binder
-        want_pods = not (
-            getattr(binder0, "bind_many_keyed", None) is not None
-            and getattr(binder0, "KEYED_NEEDS_PODS", True) is False)
-        # cache-mirror deferral: the reference's Bind is an async goroutine
-        # and its scheduler cache learns pod statuses from LATER watch
-        # events (cache.go:123-135,597-613) — only the SESSION state must be
-        # current inside the cycle. The cache-side half of this writeback
-        # (status flips, bucket moves, node maps, allocated sums on the
-        # cache twins) is therefore queued on the cache and applied at
-        # session close / before the next snapshot (cache.flush_mirror),
-        # halving the per-task work on the measured path. Bulk-bound tasks
-        # are disjoint from anything later actions touch through the cache
-        # effectors (they bind/evict PENDING/RUNNING tasks, never this
-        # session's BINDING set), and the deferred node deltas touch
-        # idle/used while evictions touch releasing — commutative.
-        defer_mirror = getattr(cache, "defer_mirror", None)
-        do_cache_inline = defer_mirror is None
-        try:
-            if fast_all is not None:
-                fast_all(
-                    job_nz_arr, seg_ends_arr, placed_arr,
-                    assign.astype(np.int64),
-                    task_infos, node_names, ssn_nodes,
-                    cache_nodes if do_cache_inline else None,
-                    job_infos,
-                    cache.jobs if do_cache_inline else None,
-                    PENDING, BINDING,
-                    np.ascontiguousarray(job_sums),
-                    tuple(scalar_names),
-                    bind_tasks, bind_pods, bind_hosts, bind_keys,
-                    int(want_pods))
-                loop_jobs = ()  # the batched call covered every job
-            else:
-                loop_jobs = job_nz
-                assign_l = assign.tolist()
-                placed_l = placed_arr.tolist()
-                job_sums_l = job_sums.tolist()
-            lo = 0
-            for ji, hi in zip(loop_jobs, seg_ends_arr.tolist()):
-                tis = placed_l[lo:hi]
-                lo = hi
-                job = job_infos[ji]
-                cache_job = cache.jobs.get(job.uid) if do_cache_inline else None
-                job._status_version += 1  # direct index surgery below
-                idx = job.task_status_index
-                s_pending = idx.get(PENDING)
-                # wholesale bucket move when the whole PENDING set placed
-                # (the common all-or-nothing gang case): O(1) instead of
-                # per-task pop+insert
-                if s_pending is not None and len(s_pending) == len(tis):
-                    s_binding = idx.get(BINDING)
-                    if s_binding is None:
-                        idx[BINDING] = s_pending
-                    else:
-                        s_binding.update(s_pending)
-                    del idx[PENDING]
-                    s_pending = None
-                    s_binding = idx[BINDING]
+        with trace.span("apply.prep"):
+            a = enc.arrays
+            t_real = len(enc.task_infos)
+            assign = assign[:t_real]
+            capped = assign == -2
+            if capped.any():
+                # diminishing-returns leftovers (rounds.py capped exit) fold
+                # into residue accounting: the serial pass retries exactly
+                # these tasks, and the fit-error stamping below skips their
+                # jobs — no stale '0/N nodes' error outlives the retry
+                cap_counts = np.bincount(
+                    a["task_job"][:t_real][capped],
+                    minlength=len(enc.job_infos)).astype(np.int32)
+                if enc.job_residue is None:
+                    enc.job_residue = cap_counts
                 else:
-                    s_binding = idx.get(BINDING)
-                    if s_binding is None:
-                        s_binding = idx[BINDING] = {}
-                if cache_job is not None:
-                    c_tasks = cache_job.tasks
-                    cache_job._status_version += 1  # direct index surgery
-                    cidx = cache_job.task_status_index
-                    c_pending = cidx.get(PENDING)
-                    if c_pending is not None and len(c_pending) == len(tis):
-                        c_binding = cidx.get(BINDING)
-                        if c_binding is None:
-                            cidx[BINDING] = c_pending
-                        else:
-                            c_binding.update(c_pending)
-                        del cidx[PENDING]
-                        c_pending = None
-                        c_binding = cidx[BINDING]
-                    else:
-                        c_binding = cidx.get(BINDING)
-                        if c_binding is None:
-                            c_binding = cidx[BINDING] = {}
+                    enc.job_residue = enc.job_residue + cap_counts
+                enc.residue_count += int(capped.sum())
+                self.profile["round_capped_tasks"] = int(capped.sum())
+                assign = np.where(capped, np.int32(-1), assign)
+            placed_mask = assign >= 0
+
+            # --- vectorized per-node / per-job resource deltas ----------------
+            node_ids = assign[placed_mask]
+            reqs = a["task_req"][:t_real][placed_mask]
+            n_count = len(enc.node_names)
+            j_count = len(enc.job_infos)
+            sums = np.zeros((n_count, reqs.shape[1]))
+            np.add.at(sums, node_ids, reqs)
+            counts = np.bincount(node_ids, minlength=n_count)
+            job_ids = a["task_job"][:t_real][placed_mask]
+            job_sums = np.zeros((j_count, reqs.shape[1]))
+            np.add.at(job_sums, job_ids, reqs)
+            job_placed_n = np.bincount(job_ids, minlength=j_count)
+
+            # resource dim names recovered from the encoder's layout
+            scalar_names = enc.resource_names[2:]
+
+            def apply_delta(res: Resource, vec, sign: float) -> None:
+                res.milli_cpu += sign * vec[0]
+                res.memory += sign * vec[1]
+                for si, name in enumerate(scalar_names):
+                    q = vec[2 + si]
+                    if q:
+                        res.add_scalar(name, sign * q)
+
+            BINDING = TaskStatus.BINDING
+            PENDING = TaskStatus.PENDING
+            task_infos = enc.task_infos
+            job_infos = enc.job_infos
+            node_names = enc.node_names
+            cache = ssn.cache
+            ssn_nodes = ssn.nodes
+            cache_nodes = cache.nodes
+            vb = cache.volume_binder
+            # volume calls are skippable when the binder is a declared no-op
+            # OR no pod in the cache references a PVC (counter maintained by
+            # the cache's task handlers) — a real StoreVolumeBinder then costs
+            # nothing on PVC-free sessions and the native loop stays eligible
+            vols_noop = getattr(vb, "IS_NOOP", False) or (
+                getattr(cache, "_pvc_pod_count", 1) == 0)
+            alloc_vols = vb.allocate_volumes
+            bind_vols = vb.bind_volumes
+
+            placed_arr = np.nonzero(placed_mask)[0]
+            job_nz_arr = np.nonzero(job_placed_n)[0]
+            seg_ends_arr = np.cumsum(job_placed_n[job_nz_arr])
+            job_nz = job_nz_arr.tolist()
+
+            # tasks are contiguous per job on the flat axis, so placed visits
+            # each job's placements as one contiguous run. The loop allocates
+            # ~1 object + a few dict entries per task; suppress the cyclic GC so
+            # gen-promotion scans of the (multi-million-object) session heap
+            # don't fire mid-apply.
+            import gc
+        with trace.span("apply.loop"):
+            gc_was = gc.isenabled()
+            gc.disable()
+            bind_tasks: list = []
+            bind_pods: list = []
+            bind_hosts: list = []
+            bind_keys: list = []
+            # native batched loop (volcano_tpu/_native/fastapply.c): identical
+            # semantics to the Python body below, which remains the fallback
+            # and oracle; volumes force the Python path (effector calls)
+            # non-blocking: a cold process compiles on a background thread
+            # and THIS session runs the Python loop; never wait on cc here
+            from volcano_tpu._native import get_fastapply_nowait
+
+            mod = get_fastapply_nowait()
+            fast_all = getattr(mod, "apply_all_jobs", None) \
+                if (mod is not None and vols_noop) else None
+            # a keyed binder that declares it does not consume pod objects
+            # (KEYED_NEEDS_PODS = False — the k8s Bind subresource needs only
+            # name + target) lets the writeback skip 50k .pod extractions;
+            # the BindManyError retry path still reads task.pod lazily
+            binder0 = cache.binder
+            want_pods = not (
+                getattr(binder0, "bind_many_keyed", None) is not None
+                and getattr(binder0, "KEYED_NEEDS_PODS", True) is False)
+            # cache-mirror deferral: the reference's Bind is an async goroutine
+            # and its scheduler cache learns pod statuses from LATER watch
+            # events (cache.go:123-135,597-613) — only the SESSION state must be
+            # current inside the cycle. The cache-side half of this writeback
+            # (status flips, bucket moves, node maps, allocated sums on the
+            # cache twins) is therefore queued on the cache and applied at
+            # session close / before the next snapshot (cache.flush_mirror),
+            # halving the per-task work on the measured path. Bulk-bound tasks
+            # are disjoint from anything later actions touch through the cache
+            # effectors (they bind/evict PENDING/RUNNING tasks, never this
+            # session's BINDING set), and the deferred node deltas touch
+            # idle/used while evictions touch releasing — commutative.
+            defer_mirror = getattr(cache, "defer_mirror", None)
+            do_cache_inline = defer_mirror is None
+            try:
+                if fast_all is not None:
+                    fast_all(
+                        job_nz_arr, seg_ends_arr, placed_arr,
+                        assign.astype(np.int64),
+                        task_infos, node_names, ssn_nodes,
+                        cache_nodes if do_cache_inline else None,
+                        job_infos,
+                        cache.jobs if do_cache_inline else None,
+                        PENDING, BINDING,
+                        np.ascontiguousarray(job_sums),
+                        tuple(scalar_names),
+                        bind_tasks, bind_pods, bind_hosts, bind_keys,
+                        int(want_pods))
+                    loop_jobs = ()  # the batched call covered every job
                 else:
-                    c_tasks = c_pending = c_binding = None
-
-                for ti in tis:
-                    task = task_infos[ti]
-                    host = node_names[assign_l[ti]]
-                    task.node_name = host
-                    task.status = BINDING
-                    uid = task.uid
-                    if s_pending is not None:
-                        s_pending.pop(uid, None)
-                        s_binding[uid] = task
-                    # the session task itself is shared into both node
-                    # task-maps (the serial path stores clones so LATER
-                    # status flips can't corrupt node accounting;
-                    # nothing flips a BINDING task in place for the
-                    # rest of this session, and cache watch events
-                    # REPLACE node entries rather than mutate them, so
-                    # the share is safe and saves one object per
-                    # placement)
-                    key = task.key
-                    node = ssn_nodes[host]
-                    node._acct_gen += 1  # invalidate snapshot node-axis
-                    node.tasks[key] = task
-                    if c_tasks is not None:
-                        ctask = c_tasks.get(uid)
-                        if ctask is not None:
-                            ctask.node_name = host
-                            ctask.status = BINDING
-                            if c_pending is not None:
-                                c_pending.pop(uid, None)
-                                c_binding[uid] = ctask
-                            cnode = cache_nodes.get(host)
-                            if cnode is not None:
-                                cnode._acct_gen += 1
-                                cnode.tasks[key] = task
-                    # effector contract matches session.dispatch ->
-                    # cache.bind (cache.py:374-395): volumes, binder
-                    if not vols_noop:
-                        alloc_vols(task, host)
-                        bind_vols(task)
-                    bind_tasks.append(task)
-                    if want_pods:
-                        bind_pods.append(task.pod)
-                    bind_hosts.append(host)
-                    bind_keys.append(key)
-
-                # PENDING -> BINDING leaves total_request unchanged;
-                # allocated grows by the job's placed sum, pending_sum
-                # shrinks by it (every placed task left the PENDING bucket)
-                vec = job_sums_l[ji]
-                apply_delta(job.allocated, vec, +1.0)
-                apply_delta(job.pending_sum, vec, -1.0)
-                if cache_job is not None:
-                    apply_delta(cache_job.allocated, vec, +1.0)
-                    apply_delta(cache_job.pending_sum, vec, -1.0)
-        finally:
-            if gc_was:
-                gc.enable()
-
-        self.profile["apply_loop_s"] = time.perf_counter() - prof_t1
-        prof_t2 = time.perf_counter()
-
-        # --- bulk node accounting (session tree; cache tree deferred) -----
-        # runs BEFORE the mirror defer so the payload can capture the final
-        # session-side node generations (the keeper's sync point)
-        node_nz = np.nonzero(counts)[0]
-        fast_nodes = getattr(mod, "apply_node_deltas", None) \
-            if mod is not None else None
-        if fast_nodes is not None:
-            fast_nodes(node_nz, np.ascontiguousarray(sums),
-                       node_names, ssn_nodes,
-                       cache_nodes if do_cache_inline else None,
-                       tuple(scalar_names))
-        else:
-            sums_l = sums.tolist()
-            for ni in node_nz.tolist():
-                vec = sums_l[ni]
-                name = node_names[ni]
-                nodes_pair = (ssn_nodes.get(name), cache_nodes.get(name)) \
-                    if do_cache_inline else (ssn_nodes.get(name),)
-                for node in nodes_pair:
-                    if node is None:
-                        continue
-                    node._acct_gen += 1  # invalidate snapshot node-axis
-                    apply_delta(node.idle, vec, -1.0)
-                    apply_delta(node.used, vec, +1.0)
-
-        if not do_cache_inline:
-            # queued only after the session-side loop SUCCEEDED (a loop
-            # failure must not leave the cache applying phantom
-            # placements), and before any effector runs — a store-backed
-            # binder can fire synchronous watch events whose handlers
-            # flush_mirror(), and they must land on a synced mirror.
-            # job_vers/node_gens are the session-side versions at this
-            # point (all bulk mutations applied): after an exact flush the
-            # cache twins equal these objects, so the snapshot keeper can
-            # re-record them as in-sync and reuse them next open.
-            # placed_req rows let the flush subtract any placement it had
-            # to skip (pod deleted in the defer window) from the node sums.
-            defer_mirror(dict(
-                job_nz=job_nz_arr, seg_ends=seg_ends_arr, placed=placed_arr,
-                assign=assign, task_infos=task_infos, node_names=node_names,
-                job_infos=job_infos, job_sums=job_sums,
-                scalar_names=tuple(scalar_names),
-                node_nz=node_nz, node_sums=sums,
-                placed_req=reqs,
-                job_vers=[job_infos[ji]._status_version
-                          for ji in job_nz],
-                node_gens=[ssn_nodes[node_names[ni]]._acct_gen
-                           for ni in node_nz.tolist()]))
-            self.profile["mirror_deferred"] = 1
-
-        # --- batch binder + events ----------------------------------------
-        binder = cache.binder
-        retry_from = None
-        keyed_bind = getattr(binder, "bind_many_keyed", None)
-        if keyed_bind is not None:
-            # the apply loop already derived each placement's ns/name key;
-            # a keyed binder skips 50k metadata re-derivations (pods is
-            # None when the binder declared KEYED_NEEDS_PODS = False)
-            try:
-                keyed_bind(bind_keys, bind_pods if want_pods else None,
-                           bind_hosts)
-            except BindManyError as e:
-                retry_from = e.done
-            except Exception:
-                retry_from = 0
-        elif hasattr(binder, "bind_many"):
-            try:
-                # pods were extracted during the apply loop; zip streams the
-                # pairs without materializing another 50k-tuple list
-                binder.bind_many(zip(bind_pods, bind_hosts))
-            except BindManyError as e:
-                retry_from = e.done
-            except Exception:
-                # bind_many contract: partial progress => BindManyError; a
-                # bare exception means nothing was bound
-                retry_from = 0
-        else:
-            retry_from = 0
-        failed_binds: set = set()
-        if retry_from is not None:
-            # per-task so one bad pod degrades to resync, not a lost
-            # session (cache.go:597-599 semantics); failures are tracked
-            # so the event record below stays bind-exact — a fenced
-            # (deposed-leader) or otherwise failed bind must not leave a
-            # phantom Scheduled event behind
-            for k, (task, host) in enumerate(
-                    zip(bind_tasks[retry_from:], bind_hosts[retry_from:]),
-                    start=retry_from):
-                try:
-                    binder.bind(task.pod, host)
-                except Exception:
-                    cache.resync_task(task)
-                    failed_binds.add(k)
-        if cache.store is not None:
-            event_keys, event_hosts, event_tasks = (
-                bind_keys, bind_hosts, bind_tasks)
-            if failed_binds:
-                event_keys = [k for i, k in enumerate(bind_keys)
-                              if i not in failed_binds]
-                event_hosts = [h for i, h in enumerate(bind_hosts)
-                               if i not in failed_binds]
-                event_tasks = [t for i, t in enumerate(bind_tasks)
-                               if i not in failed_binds]
-            record_scheduled = getattr(cache.store, "record_scheduled", None)
-            if record_scheduled is not None:
-                # lazy batch record: the Scheduled message materializes on
-                # read, not on the session's critical path (the reference
-                # recorder is an async broadcaster — cache.go:601-611)
-                record_scheduled(event_keys, event_hosts)
-            else:
-                cache.store.record_events(
-                    (task.pod, "Normal", "Scheduled",
-                     f"Successfully assigned "
-                     f"{task.namespace}/{task.name} to {host}")
-                    for task, host in zip(event_tasks, event_hosts))
-
-        if enc.spec.use_exclusion:
-            # device-placed exclusion-group pods carry required
-            # anti-affinity: later serial phases (residue, backfill,
-            # preempt) must see them in the predicates plugin's resident
-            # index, which the bulk writeback's event bypass would miss
-            pred = ssn.plugins.get("predicates")
-            note = getattr(pred, "note_resident", None)
-            if note is not None:
-                from volcano_tpu.api.pod_traits import has_pod_affinity
-
-                for task in bind_tasks:
-                    if task.pod is not None and has_pod_affinity(task.pod):
-                        note(task)
-
-        self.profile["apply_bind_s"] = time.perf_counter() - prof_t2
-        prof_t3 = time.perf_counter()
-
-        # --- bulk plugin share updates (drf / proportion) -----------------
-        # per-job DRF shares must be exact per job; namespace/queue shares
-        # aggregate across jobs, so accumulate the deltas in numpy and touch
-        # each namespace/queue attr once
-        drf = ssn.plugins.get("drf")
-        prop = ssn.plugins.get("proportion")
-        if drf is not None:
-            fast_drf = getattr(mod, "update_drf_shares", None) \
-                if mod is not None else None
-            if fast_drf is not None:
-                attrs = [drf.job_attrs.get(job_infos[ji].uid)
-                         for ji in job_nz]
-                tnames = tuple(drf.total_resource.resource_names())
-                tvals = np.array([drf.total_resource.get(n) for n in tnames])
-                fast_drf(np.asarray(job_nz, np.int64),
-                         np.ascontiguousarray(job_sums),
-                         attrs, tnames, tvals, tuple(scalar_names))
-            else:
-                job_sums_rows = job_sums_l if fast_all is None else \
-                    job_sums.tolist()
-                for ji in job_nz:
+                    loop_jobs = job_nz
+                    assign_l = assign.tolist()
+                    placed_l = placed_arr.tolist()
+                    job_sums_l = job_sums.tolist()
+                lo = 0
+                for ji, hi in zip(loop_jobs, seg_ends_arr.tolist()):
+                    tis = placed_l[lo:hi]
+                    lo = hi
                     job = job_infos[ji]
-                    attr = drf.job_attrs.get(job.uid)
-                    if attr is not None:
-                        apply_delta(attr.allocated, job_sums_rows[ji], +1.0)
-                        drf._update_share(attr)
-        if (drf is not None and drf.namespace_opts) or prop is not None:
-            ns_count_enc = int(a["ns_active0"].shape[0])
-            q_count_enc = int(a["queue_deserved"].shape[0])
-            ns_sums = np.zeros((ns_count_enc, job_sums.shape[1]))
-            q_sums = np.zeros((q_count_enc, job_sums.shape[1]))
-            np.add.at(ns_sums, a["job_ns"][job_nz], job_sums[job_nz])
-            np.add.at(q_sums, a["job_queue"][job_nz], job_sums[job_nz])
-            ns_sums_l = ns_sums.tolist()
-            q_sums_l = q_sums.tolist()
-            if drf is not None and drf.namespace_opts:
-                for nsi in np.nonzero(ns_sums.any(axis=1))[0].tolist():
-                    ns_opt = drf.namespace_opts.get(enc.ns_names[nsi])
-                    if ns_opt is not None:
-                        apply_delta(ns_opt.allocated, ns_sums_l[nsi], +1.0)
-                        drf._update_share(ns_opt)
-            if prop is not None:
-                for qi in np.nonzero(q_sums.any(axis=1))[0].tolist():
-                    attr = prop.queue_opts.get(enc.queue_uids[qi])
-                    if attr is not None:
-                        apply_delta(attr.allocated, q_sums_l[qi], +1.0)
-                        prop._update_share(attr)
+                    cache_job = cache.jobs.get(job.uid) if do_cache_inline else None
+                    job._status_version += 1  # direct index surgery below
+                    idx = job.task_status_index
+                    s_pending = idx.get(PENDING)
+                    # wholesale bucket move when the whole PENDING set placed
+                    # (the common all-or-nothing gang case): O(1) instead of
+                    # per-task pop+insert
+                    if s_pending is not None and len(s_pending) == len(tis):
+                        s_binding = idx.get(BINDING)
+                        if s_binding is None:
+                            idx[BINDING] = s_pending
+                        else:
+                            s_binding.update(s_pending)
+                        del idx[PENDING]
+                        s_pending = None
+                        s_binding = idx[BINDING]
+                    else:
+                        s_binding = idx.get(BINDING)
+                        if s_binding is None:
+                            s_binding = idx[BINDING] = {}
+                    if cache_job is not None:
+                        c_tasks = cache_job.tasks
+                        cache_job._status_version += 1  # direct index surgery
+                        cidx = cache_job.task_status_index
+                        c_pending = cidx.get(PENDING)
+                        if c_pending is not None and len(c_pending) == len(tis):
+                            c_binding = cidx.get(BINDING)
+                            if c_binding is None:
+                                cidx[BINDING] = c_pending
+                            else:
+                                c_binding.update(c_pending)
+                            del cidx[PENDING]
+                            c_pending = None
+                            c_binding = cidx[BINDING]
+                        else:
+                            c_binding = cidx.get(BINDING)
+                            if c_binding is None:
+                                c_binding = cidx[BINDING] = {}
+                    else:
+                        c_tasks = c_pending = c_binding = None
 
-        # --- fit errors for gangs the solve could not complete ------------
-        start, count = a["job_task_start"], a["job_task_count"]
-        job_residue = enc.job_residue
-        for ji in np.nonzero(job_placed_n < count)[0].tolist():
-            job = job_infos[ji]
-            lo, hi = int(start[ji]), int(start[ji]) + int(count[ji])
-            if lo == hi or job.ready():
-                continue
-            if (job_residue is not None and job_residue[ji]) or enc.has_releasing:
-                # the serial pass retries this job (residue tasks, or
-                # releasing capacity it may pipeline onto) with full
-                # predicate fidelity; it records its own fit errors —
-                # mirror allocate.py's retry condition so no stale
-                # '0/N nodes' error outlives a successful retry
-                continue
-            first = lo + int(np.argmax(assign[lo:hi] < 0))
-            fe = FitErrors()
-            fe.set_error(
-                "0/%d nodes are available in the batched "
-                "feasibility/fit solve" % n_count)
-            job.nodes_fit_errors[task_infos[first].uid] = fe
-        self.profile["apply_post_s"] = time.perf_counter() - prof_t3
+                    for ti in tis:
+                        task = task_infos[ti]
+                        host = node_names[assign_l[ti]]
+                        task.node_name = host
+                        task.status = BINDING
+                        uid = task.uid
+                        if s_pending is not None:
+                            s_pending.pop(uid, None)
+                            s_binding[uid] = task
+                        # the session task itself is shared into both node
+                        # task-maps (the serial path stores clones so LATER
+                        # status flips can't corrupt node accounting;
+                        # nothing flips a BINDING task in place for the
+                        # rest of this session, and cache watch events
+                        # REPLACE node entries rather than mutate them, so
+                        # the share is safe and saves one object per
+                        # placement)
+                        key = task.key
+                        node = ssn_nodes[host]
+                        node._acct_gen += 1  # invalidate snapshot node-axis
+                        node.tasks[key] = task
+                        if c_tasks is not None:
+                            ctask = c_tasks.get(uid)
+                            if ctask is not None:
+                                ctask.node_name = host
+                                ctask.status = BINDING
+                                if c_pending is not None:
+                                    c_pending.pop(uid, None)
+                                    c_binding[uid] = ctask
+                                cnode = cache_nodes.get(host)
+                                if cnode is not None:
+                                    cnode._acct_gen += 1
+                                    cnode.tasks[key] = task
+                        # effector contract matches session.dispatch ->
+                        # cache.bind (cache.py:374-395): volumes, binder
+                        if not vols_noop:
+                            alloc_vols(task, host)
+                            bind_vols(task)
+                        bind_tasks.append(task)
+                        if want_pods:
+                            bind_pods.append(task.pod)
+                        bind_hosts.append(host)
+                        bind_keys.append(key)
+
+                    # PENDING -> BINDING leaves total_request unchanged;
+                    # allocated grows by the job's placed sum, pending_sum
+                    # shrinks by it (every placed task left the PENDING bucket)
+                    vec = job_sums_l[ji]
+                    apply_delta(job.allocated, vec, +1.0)
+                    apply_delta(job.pending_sum, vec, -1.0)
+                    if cache_job is not None:
+                        apply_delta(cache_job.allocated, vec, +1.0)
+                        apply_delta(cache_job.pending_sum, vec, -1.0)
+            finally:
+                if gc_was:
+                    gc.enable()
+
+        with trace.span("apply.bind"):
+
+            # --- bulk node accounting (session tree; cache tree deferred) -----
+            # runs BEFORE the mirror defer so the payload can capture the final
+            # session-side node generations (the keeper's sync point)
+            node_nz = np.nonzero(counts)[0]
+            fast_nodes = getattr(mod, "apply_node_deltas", None) \
+                if mod is not None else None
+            if fast_nodes is not None:
+                fast_nodes(node_nz, np.ascontiguousarray(sums),
+                           node_names, ssn_nodes,
+                           cache_nodes if do_cache_inline else None,
+                           tuple(scalar_names))
+            else:
+                sums_l = sums.tolist()
+                for ni in node_nz.tolist():
+                    vec = sums_l[ni]
+                    name = node_names[ni]
+                    nodes_pair = (ssn_nodes.get(name), cache_nodes.get(name)) \
+                        if do_cache_inline else (ssn_nodes.get(name),)
+                    for node in nodes_pair:
+                        if node is None:
+                            continue
+                        node._acct_gen += 1  # invalidate snapshot node-axis
+                        apply_delta(node.idle, vec, -1.0)
+                        apply_delta(node.used, vec, +1.0)
+
+            if not do_cache_inline:
+                # queued only after the session-side loop SUCCEEDED (a loop
+                # failure must not leave the cache applying phantom
+                # placements), and before any effector runs — a store-backed
+                # binder can fire synchronous watch events whose handlers
+                # flush_mirror(), and they must land on a synced mirror.
+                # job_vers/node_gens are the session-side versions at this
+                # point (all bulk mutations applied): after an exact flush the
+                # cache twins equal these objects, so the snapshot keeper can
+                # re-record them as in-sync and reuse them next open.
+                # placed_req rows let the flush subtract any placement it had
+                # to skip (pod deleted in the defer window) from the node sums.
+                defer_mirror(dict(
+                    job_nz=job_nz_arr, seg_ends=seg_ends_arr, placed=placed_arr,
+                    assign=assign, task_infos=task_infos, node_names=node_names,
+                    job_infos=job_infos, job_sums=job_sums,
+                    scalar_names=tuple(scalar_names),
+                    node_nz=node_nz, node_sums=sums,
+                    placed_req=reqs,
+                    job_vers=[job_infos[ji]._status_version
+                              for ji in job_nz],
+                    node_gens=[ssn_nodes[node_names[ni]]._acct_gen
+                               for ni in node_nz.tolist()]))
+                self.profile["mirror_deferred"] = 1
+
+            # --- batch binder + events ----------------------------------------
+            binder = cache.binder
+            retry_from = None
+            keyed_bind = getattr(binder, "bind_many_keyed", None)
+            if keyed_bind is not None:
+                # the apply loop already derived each placement's ns/name key;
+                # a keyed binder skips 50k metadata re-derivations (pods is
+                # None when the binder declared KEYED_NEEDS_PODS = False)
+                try:
+                    keyed_bind(bind_keys, bind_pods if want_pods else None,
+                               bind_hosts)
+                except BindManyError as e:
+                    retry_from = e.done
+                except Exception:
+                    retry_from = 0
+            elif hasattr(binder, "bind_many"):
+                try:
+                    # pods were extracted during the apply loop; zip streams the
+                    # pairs without materializing another 50k-tuple list
+                    binder.bind_many(zip(bind_pods, bind_hosts))
+                except BindManyError as e:
+                    retry_from = e.done
+                except Exception:
+                    # bind_many contract: partial progress => BindManyError; a
+                    # bare exception means nothing was bound
+                    retry_from = 0
+            else:
+                retry_from = 0
+            failed_binds: set = set()
+            if retry_from is not None:
+                # per-task so one bad pod degrades to resync, not a lost
+                # session (cache.go:597-599 semantics); failures are tracked
+                # so the event record below stays bind-exact — a fenced
+                # (deposed-leader) or otherwise failed bind must not leave a
+                # phantom Scheduled event behind
+                for k, (task, host) in enumerate(
+                        zip(bind_tasks[retry_from:], bind_hosts[retry_from:]),
+                        start=retry_from):
+                    try:
+                        binder.bind(task.pod, host)
+                    except Exception:
+                        cache.resync_task(task)
+                        failed_binds.add(k)
+            if cache.store is not None:
+                event_keys, event_hosts, event_tasks = (
+                    bind_keys, bind_hosts, bind_tasks)
+                if failed_binds:
+                    event_keys = [k for i, k in enumerate(bind_keys)
+                                  if i not in failed_binds]
+                    event_hosts = [h for i, h in enumerate(bind_hosts)
+                                   if i not in failed_binds]
+                    event_tasks = [t for i, t in enumerate(bind_tasks)
+                                   if i not in failed_binds]
+                record_scheduled = getattr(cache.store, "record_scheduled", None)
+                if record_scheduled is not None:
+                    # lazy batch record: the Scheduled message materializes on
+                    # read, not on the session's critical path (the reference
+                    # recorder is an async broadcaster — cache.go:601-611)
+                    record_scheduled(event_keys, event_hosts)
+                else:
+                    cache.store.record_events(
+                        (task.pod, "Normal", "Scheduled",
+                         f"Successfully assigned "
+                         f"{task.namespace}/{task.name} to {host}")
+                        for task, host in zip(event_tasks, event_hosts))
+
+            if enc.spec.use_exclusion:
+                # device-placed exclusion-group pods carry required
+                # anti-affinity: later serial phases (residue, backfill,
+                # preempt) must see them in the predicates plugin's resident
+                # index, which the bulk writeback's event bypass would miss
+                pred = ssn.plugins.get("predicates")
+                note = getattr(pred, "note_resident", None)
+                if note is not None:
+                    from volcano_tpu.api.pod_traits import has_pod_affinity
+
+                    for task in bind_tasks:
+                        if task.pod is not None and has_pod_affinity(task.pod):
+                            note(task)
+
+        with trace.span("apply.post"):
+
+            # --- bulk plugin share updates (drf / proportion) -----------------
+            # per-job DRF shares must be exact per job; namespace/queue shares
+            # aggregate across jobs, so accumulate the deltas in numpy and touch
+            # each namespace/queue attr once
+            drf = ssn.plugins.get("drf")
+            prop = ssn.plugins.get("proportion")
+            if drf is not None:
+                fast_drf = getattr(mod, "update_drf_shares", None) \
+                    if mod is not None else None
+                if fast_drf is not None:
+                    attrs = [drf.job_attrs.get(job_infos[ji].uid)
+                             for ji in job_nz]
+                    tnames = tuple(drf.total_resource.resource_names())
+                    tvals = np.array([drf.total_resource.get(n) for n in tnames])
+                    fast_drf(np.asarray(job_nz, np.int64),
+                             np.ascontiguousarray(job_sums),
+                             attrs, tnames, tvals, tuple(scalar_names))
+                else:
+                    job_sums_rows = job_sums_l if fast_all is None else \
+                        job_sums.tolist()
+                    for ji in job_nz:
+                        job = job_infos[ji]
+                        attr = drf.job_attrs.get(job.uid)
+                        if attr is not None:
+                            apply_delta(attr.allocated, job_sums_rows[ji], +1.0)
+                            drf._update_share(attr)
+            if (drf is not None and drf.namespace_opts) or prop is not None:
+                ns_count_enc = int(a["ns_active0"].shape[0])
+                q_count_enc = int(a["queue_deserved"].shape[0])
+                ns_sums = np.zeros((ns_count_enc, job_sums.shape[1]))
+                q_sums = np.zeros((q_count_enc, job_sums.shape[1]))
+                np.add.at(ns_sums, a["job_ns"][job_nz], job_sums[job_nz])
+                np.add.at(q_sums, a["job_queue"][job_nz], job_sums[job_nz])
+                ns_sums_l = ns_sums.tolist()
+                q_sums_l = q_sums.tolist()
+                if drf is not None and drf.namespace_opts:
+                    for nsi in np.nonzero(ns_sums.any(axis=1))[0].tolist():
+                        ns_opt = drf.namespace_opts.get(enc.ns_names[nsi])
+                        if ns_opt is not None:
+                            apply_delta(ns_opt.allocated, ns_sums_l[nsi], +1.0)
+                            drf._update_share(ns_opt)
+                if prop is not None:
+                    for qi in np.nonzero(q_sums.any(axis=1))[0].tolist():
+                        attr = prop.queue_opts.get(enc.queue_uids[qi])
+                        if attr is not None:
+                            apply_delta(attr.allocated, q_sums_l[qi], +1.0)
+                            prop._update_share(attr)
+
+            # --- fit errors for gangs the solve could not complete ------------
+            start, count = a["job_task_start"], a["job_task_count"]
+            job_residue = enc.job_residue
+            for ji in np.nonzero(job_placed_n < count)[0].tolist():
+                job = job_infos[ji]
+                lo, hi = int(start[ji]), int(start[ji]) + int(count[ji])
+                if lo == hi or job.ready():
+                    continue
+                if (job_residue is not None and job_residue[ji]) or enc.has_releasing:
+                    # the serial pass retries this job (residue tasks, or
+                    # releasing capacity it may pipeline onto) with full
+                    # predicate fidelity; it records its own fit errors —
+                    # mirror allocate.py's retry condition so no stale
+                    # '0/N nodes' error outlives a successful retry
+                    continue
+                first = lo + int(np.argmax(assign[lo:hi] < 0))
+                fe = FitErrors()
+                fe.set_error(
+                    "0/%d nodes are available in the batched "
+                    "feasibility/fit solve" % n_count)
+                job.nodes_fit_errors[task_infos[first].uid] = fe
 
 

@@ -84,12 +84,15 @@ from volcano_tpu.api import objects
 from volcano_tpu.api.types import TaskStatus
 from volcano_tpu.scheduler import metrics
 from volcano_tpu.scheduler.framework import (
+    action_span,
     close_session,
     get_action,
     open_session,
+    run_action,
     run_actions,
     takeover_recovery_sweep,
 )
+from volcano_tpu.utils import trace
 
 logger = logging.getLogger(__name__)
 
@@ -501,44 +504,32 @@ class PipelineDriver:
             return ssn
         self._preamble(ssn)
         action_ms: Dict[str, float] = {}
-        t0 = time.perf_counter()
         if "enqueue" in names:
-            get_action("enqueue").execute(ssn)
-            action_ms["enqueue"] = round(
-                (time.perf_counter() - t0) * 1e3, 3)
-        solver = getattr(ssn, "batch_allocator", None)
-        prep = solver._prepare(ssn) if solver is not None else None
-        t0 = time.perf_counter()
-        if prep is None or prep["mode"] != "rounds" \
-                or prep["staged"] is None:
-            # sub-threshold / fallback sessions: the allocate action owns
-            # its own solver ladder (serial oracle included)
-            info["mode"] = "per_action"
-            for name in names:
-                if name == "enqueue":
-                    continue
-                t1 = time.perf_counter()
-                get_action(name).execute(ssn)
-                action_ms[name] = round(
-                    (time.perf_counter() - t1) * 1e3, 3)
-            info["action_ms"] = action_ms
-            return ssn
-        if self._solve_and_apply(ssn, solver, prep, wait=None):
-            from volcano_tpu.scheduler.actions.allocate import \
-                finish_batched
+            run_action(ssn, "enqueue", action_ms)
+        with action_span("allocate", action_ms):
+            solver = getattr(ssn, "batch_allocator", None)
+            prep = solver._prepare(ssn) if solver is not None else None
+            packed = prep is not None and prep["mode"] == "rounds" \
+                and prep["staged"] is not None
+            if not packed:
+                # sub-threshold / fallback sessions: the allocate action
+                # owns its own solver ladder (serial oracle included)
+                info["mode"] = "per_action"
+                get_action("allocate").execute(ssn)
+            elif self._solve_and_apply(ssn, solver, prep, wait=None):
+                from volcano_tpu.scheduler.actions.allocate import \
+                    finish_batched
 
-            finish_batched(ssn, solver)
-        else:
-            # dispatch/fetch failure: the allocate action retries through
-            # its own fallback ladder (serial host solve), which runs
-            # finish_batched itself when the retry lands batched
-            get_action("allocate").execute(ssn)
-        action_ms["allocate"] = round((time.perf_counter() - t0) * 1e3, 3)
-        if "backfill" in names:
-            t1 = time.perf_counter()
-            get_action("backfill").execute(ssn)
-            action_ms["backfill"] = round(
-                (time.perf_counter() - t1) * 1e3, 3)
+                finish_batched(ssn, solver)
+            else:
+                # dispatch/fetch failure: the allocate action retries
+                # through its own fallback ladder (serial host solve),
+                # which runs finish_batched itself when the retry lands
+                # batched
+                get_action("allocate").execute(ssn)
+        for name in names:
+            if name not in ("enqueue", "allocate"):
+                run_action(ssn, name, action_ms)
         info.setdefault("mode", "pipelined")
         info["action_ms"] = action_ms
         return ssn
@@ -554,15 +545,11 @@ class PipelineDriver:
             if wait is None:
                 from volcano_tpu.ops import rounds as rounds_mod
 
-                tp = time.perf_counter()
-                wait = devprof.start_fetch(rounds_mod.solve_rounds_packed(
-                    prep["spec"], prep["layout"], prep["staged"]))
-                out = wait()
-                solver.profile["pack_s"] = prep["pack_s"]
-                solver.profile["h2d_s"] = prep["h2d_s"]
-                solver.profile["dispatch_s"] = time.perf_counter() - tp
-            else:
-                out = wait()
+                with trace.span("dispatch"):
+                    wait = devprof.start_fetch(
+                        rounds_mod.solve_rounds_packed(
+                            prep["spec"], prep["layout"], prep["staged"]))
+            out = wait()
             assign, meta = solver.parse_packed(out)
         except Exception as e:
             logger.exception("pipeline solve failed; serial fallback")
@@ -631,9 +618,10 @@ class PipelineDriver:
             from volcano_tpu.utils import devprof
 
             t_dispatch = time.perf_counter()
-            dev = rounds_mod.solve_rounds_packed(
-                prep["spec"], prep["layout"], prep["staged"])
-            wait = devprof.start_fetch(dev)
+            with trace.span("dispatch", speculative=1):
+                dev = rounds_mod.solve_rounds_packed(
+                    prep["spec"], prep["layout"], prep["staged"])
+                wait = devprof.start_fetch(dev)
         except Exception:
             logger.exception("speculative dispatch failed; cycle will "
                              "run serially")
@@ -746,7 +734,6 @@ class PipelineDriver:
         re-runs the cycle serially; nothing was applied)."""
         ssn = st.ssn
         solver = ssn.batch_allocator
-        t0 = time.perf_counter()
         # quiet commit: no outstanding tokens by fingerprint, reconcile
         # still bumps the lane's session seq. Read-set commit: post-seal
         # tokens (already proven disjoint) defer past this session.
@@ -770,9 +757,16 @@ class PipelineDriver:
             devprof.discard(st.dev)
             self._release(ssn)
             return None
-        t_wait = time.perf_counter()
-        overlap_s = t_wait - st.t_dispatch
-        if not self._solve_and_apply(ssn, solver, st.prep, wait=st.fetch):
+        from volcano_tpu.scheduler.actions.allocate import finish_batched
+
+        overlap_s = time.perf_counter() - st.t_dispatch
+        action_ms: Dict[str, float] = {}
+        with action_span("allocate", action_ms):
+            applied = self._solve_and_apply(ssn, solver, st.prep,
+                                            wait=st.fetch)
+            if applied:
+                finish_batched(ssn, solver)
+        if not applied:
             # fetch failed: treat exactly like a discard — nothing from
             # this stage was applied — and let the caller re-run
             self._note_discard("kernel_error")
@@ -781,16 +775,8 @@ class PipelineDriver:
             self._revert_flips(st)
             self._release(ssn)
             return None
-        from volcano_tpu.scheduler.actions.allocate import finish_batched
-
-        finish_batched(ssn, solver)
-        action_ms = {"allocate": round(
-            (time.perf_counter() - t0) * 1e3, 3)}
         if "backfill" in st.names:
-            t1 = time.perf_counter()
-            get_action("backfill").execute(ssn)
-            action_ms["backfill"] = round(
-                (time.perf_counter() - t1) * 1e3, 3)
+            run_action(ssn, "backfill", action_ms)
         self.stats["spec_applied"] += 1
         kind = st.commit_kind
         commits = self.stats["spec_commits"]

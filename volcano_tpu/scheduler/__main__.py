@@ -15,6 +15,9 @@ Maps the reference's flag surface (cmd/scheduler/app/options/options.go:78-108
   so a standalone run has something to schedule; without an external API
   server the full cluster (controllers + kubelet sim) runs in-process.
 
+``--profiler-port`` serves the JAX profiler, so xprof or TensorBoard can
+capture a live scheduler's ``vt.*`` spans (volcano_tpu/utils/trace.py).
+
 ``--run-for N`` exits after N seconds (the e2e/smoke hook); default runs
 until SIGINT.
 """
@@ -97,6 +100,10 @@ def parse_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--insecure-skip-tls-verify", action="store_true",
                     help="accept self-signed gateway certificates "
                          "(https --server)")
+    ap.add_argument("--profiler-port", type=int, default=0,
+                    help="serve the JAX profiler on this port so xprof or "
+                         "TensorBoard can capture the scheduler's vt.* "
+                         "spans live (0 = off)")
     ap.add_argument("--run-for", type=float, default=0.0,
                     help="exit after N seconds (0 = until SIGINT)")
     ap.add_argument("--version", action="store_true")
@@ -280,6 +287,16 @@ def run_remote_scheduler(args) -> int:
     return 0
 
 
+def start_profiler(port: int):
+    """Serve the JAX profiler on ``port``; 0 starts nothing."""
+    if not port:
+        return None
+    import jax.profiler
+
+    logging.info("profiler server on :%d", port)
+    return jax.profiler.start_server(port)
+
+
 def main(argv=None) -> int:
     args = parse_args(argv)
     if args.version:
@@ -301,6 +318,7 @@ def main(argv=None) -> int:
     o.percentage_of_nodes_to_find = args.percentage_of_nodes_to_find
     o.listen_address = args.listen_address
     o.healthz_address = args.healthz_address
+    start_profiler(args.profiler_port)
 
     if args.server:
         return run_remote_scheduler(args)

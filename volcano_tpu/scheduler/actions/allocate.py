@@ -26,6 +26,7 @@ from volcano_tpu.scheduler.util.priority_queue import (
     PriorityQueue,
     make_task_queue,
 )
+from volcano_tpu.utils import trace
 
 logger = logging.getLogger(__name__)
 
@@ -52,22 +53,19 @@ def finish_batched(ssn, solver) -> None:
         # alloc assist (vectorized window + cached score rows, live
         # residual affinity/ports checks) replaces the per-node
         # closure sweeps with bit-identical selections.
-        import time
-
         from volcano_tpu.ops import preemptview
 
         logger.info(
             "allocate: serial residue pass (%d residue tasks, "
             "%d unplaced)", residue, unplaced)
-        t0 = time.perf_counter()
-        AllocateAction()._serial_execute(
-            ssn, assist=preemptview.build_alloc_assist(ssn))
+        with trace.span("residue", tasks=residue + unplaced) as sp:
+            AllocateAction()._serial_execute(
+                ssn, assist=preemptview.build_alloc_assist(ssn))
         # the tail the device solve left to the host, as first-class
         # profile terms (bench: tpu_residue_ms / tpu_residue_tasks)
         # — the candidate-window straggler rounds exist to shrink
         # exactly these numbers
-        prof["residue_pass_ms"] = round(
-            (time.perf_counter() - t0) * 1e3, 3)
+        prof["residue_pass_ms"] = round(sp.elapsed * 1e3, 3)
         prof["residue_pass_tasks"] = residue + (
             unplaced if prof.get("has_releasing") else 0)
 

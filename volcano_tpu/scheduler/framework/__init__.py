@@ -13,8 +13,10 @@ from volcano_tpu.scheduler.framework.event_handlers import Event, EventHandler
 from volcano_tpu.scheduler.framework.session import Session
 from volcano_tpu.scheduler.framework.statement import Statement
 from volcano_tpu.scheduler.framework.framework import (
+    action_span,
     open_session,
     close_session,
+    run_action,
     run_actions,
     takeover_recovery_sweep,
 )

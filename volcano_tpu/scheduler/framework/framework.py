@@ -2,9 +2,9 @@
 
 from __future__ import annotations
 
+import contextlib
 import logging
-import time
-from typing import List
+from typing import Dict, List
 
 from volcano_tpu.api.types import TaskStatus, allocated_status
 from volcano_tpu.scheduler import conf
@@ -13,6 +13,7 @@ from volcano_tpu.scheduler.framework.arguments import Arguments
 from volcano_tpu.scheduler.framework.job_updater import JobUpdater
 from volcano_tpu.scheduler.framework.plugins import get_plugin_builder
 from volcano_tpu.scheduler.framework.session import Session, open_session_state
+from volcano_tpu.utils import trace
 
 logger = logging.getLogger(__name__)
 
@@ -39,9 +40,9 @@ def open_session(cache, tiers: List[conf.Tier]) -> Session:
             ssn.plugins[plugin.name()] = plugin
 
     for plugin in ssn.plugins.values():
-        start = time.perf_counter()
-        plugin.on_session_open(ssn)
-        metrics.update_plugin_duration(plugin.name(), "OnSessionOpen", time.perf_counter() - start)
+        with trace.span("open.plugin." + plugin.name()) as sp:
+            plugin.on_session_open(ssn)
+        metrics.update_plugin_duration(plugin.name(), "OnSessionOpen", sp.elapsed)
     return ssn
 
 
@@ -85,15 +86,31 @@ def takeover_recovery_sweep(ssn) -> int:
     return reverted
 
 
+@contextlib.contextmanager
+def action_span(name: str, action_ms: Dict[str, float]):
+    """The one place an action's wall time is taken: the ``vt.action.<name>``
+    span around the block, added to ``action_ms[name]`` (ms) and to the
+    action-duration histogram. A block that raises records nothing, so
+    ``name in action_ms`` says the action ran to its end."""
+    with trace.span("action." + name) as sp:
+        yield sp
+    action_ms[name] = round(action_ms.get(name, 0.0) + sp.elapsed * 1e3, 3)
+    metrics.update_action_duration(name, sp.elapsed)
+
+
+def run_action(ssn: Session, name: str, action_ms: Dict[str, float]) -> None:
+    from volcano_tpu.scheduler.framework.plugins import get_action
+
+    with action_span(name, action_ms):
+        get_action(name).execute(ssn)
+
+
 def run_actions(ssn: Session, actions) -> dict:
     """Run the session's action chain, preferring the whole-session fused
     dispatch (ops/session_fuse.py) when the session is inside its envelope;
     otherwise the plain per-action loop. ``actions`` is a sequence of
-    action names or Action instances. Returns {action name: wall ms} — the
-    per-action timings every caller (scheduler loop, bench, simulator) used
-    to collect itself."""
-    from volcano_tpu.scheduler.framework.plugins import get_action
-
+    action names or Action instances. Returns {action name: wall ms}, each
+    action timed by ``action_span``."""
     names = [a if isinstance(a, str) else a.name() for a in actions]
     if getattr(ssn.cache, "express_lane", None) is not None:
         # reconcile every outstanding express bind FIRST: the session is
@@ -115,11 +132,9 @@ def run_actions(ssn: Session, actions) -> dict:
         out = session_fuse.try_run(ssn, names)
         if out is not None:
             return out
-    action_ms = {}
+    action_ms: Dict[str, float] = {}
     for name in names:
-        t0 = time.perf_counter()
-        get_action(name).execute(ssn)
-        action_ms[name] = round((time.perf_counter() - t0) * 1e3, 3)
+        run_action(ssn, name, action_ms)
     return action_ms
 
 
@@ -130,7 +145,8 @@ def close_session(ssn: Session) -> None:
     # on_session_close and the job updater read the cache below
     flush = getattr(ssn.cache, "flush_mirror", None)
     if flush is not None:
-        flush()
+        with trace.span("close.flush_mirror"):
+            flush()
     # volume assumptions not bound by session end belong to placements
     # that never dispatched (e.g. a gang that stayed short) — release
     # them, or their PVs stay unselectable forever (assume/bind always
@@ -140,11 +156,12 @@ def close_session(ssn: Session) -> None:
     if reset_assumed is not None:
         reset_assumed()
     for plugin in ssn.plugins.values():
-        start = time.perf_counter()
-        plugin.on_session_close(ssn)
-        metrics.update_plugin_duration(plugin.name(), "OnSessionClose", time.perf_counter() - start)
+        with trace.span("close.plugin." + plugin.name()) as sp:
+            plugin.on_session_close(ssn)
+        metrics.update_plugin_duration(plugin.name(), "OnSessionClose", sp.elapsed)
 
-    JobUpdater(ssn).update_all()
+    with trace.span("close.job_updater", jobs=len(ssn.jobs)):
+        JobUpdater(ssn).update_all()
 
     ssn.jobs = {}
     ssn.nodes = {}

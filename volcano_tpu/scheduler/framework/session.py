@@ -25,6 +25,7 @@ from volcano_tpu.api.queue_info import QueueInfo
 from volcano_tpu.api.types import TaskStatus, allocated_status
 from volcano_tpu.scheduler import conf
 from volcano_tpu.scheduler.framework.event_handlers import Event, EventHandler
+from volcano_tpu.utils import trace
 
 
 class Session:
@@ -594,27 +595,29 @@ def job_status(ssn: Session, job_info: JobInfo) -> objects.PodGroupStatus:
 def open_session_state(ssn: Session) -> None:
     """Fill the session from the cache snapshot and drop invalid jobs
     (session.go:72-139)."""
-    snapshot: ClusterInfo = ssn.cache.snapshot()
-    ssn.jobs = snapshot.jobs
-    for job in list(ssn.jobs.values()):
-        if job.pod_group is not None and job.pod_group.status.conditions:
-            ssn.pod_group_status[job.uid] = job.pod_group.status.clone()
-        vjr = ssn.job_valid(job)
-        if vjr is not None:
-            if not vjr.pass_:
-                jc = objects.PodGroupCondition(
-                    type=objects.POD_GROUP_UNSCHEDULABLE_TYPE,
-                    status="True",
-                    transition_id=ssn.uid,
-                    reason=vjr.reason,
-                    message=vjr.message,
-                )
-                try:
-                    ssn.update_job_condition(job, jc)
-                except (KeyError, AttributeError):
-                    pass
-            del ssn.jobs[job.uid]
-    ssn.nodes = snapshot.nodes
-    ssn.queues = snapshot.queues
-    ssn.namespace_info = snapshot.namespace_info
-    ssn.node_axis = snapshot.node_axis
+    with trace.span("open.snapshot") as sp:
+        snapshot: ClusterInfo = ssn.cache.snapshot()
+        ssn.jobs = snapshot.jobs
+        for job in list(ssn.jobs.values()):
+            if job.pod_group is not None and job.pod_group.status.conditions:
+                ssn.pod_group_status[job.uid] = job.pod_group.status.clone()
+            vjr = ssn.job_valid(job)
+            if vjr is not None:
+                if not vjr.pass_:
+                    jc = objects.PodGroupCondition(
+                        type=objects.POD_GROUP_UNSCHEDULABLE_TYPE,
+                        status="True",
+                        transition_id=ssn.uid,
+                        reason=vjr.reason,
+                        message=vjr.message,
+                    )
+                    try:
+                        ssn.update_job_condition(job, jc)
+                    except (KeyError, AttributeError):
+                        pass
+                del ssn.jobs[job.uid]
+        ssn.nodes = snapshot.nodes
+        ssn.queues = snapshot.queues
+        ssn.namespace_info = snapshot.namespace_info
+        ssn.node_axis = snapshot.node_axis
+        sp.note(jobs=len(ssn.jobs), nodes=len(ssn.nodes))

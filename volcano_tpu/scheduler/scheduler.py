@@ -26,6 +26,7 @@ from volcano_tpu.scheduler.framework import (
     open_session,
     run_actions,
 )
+from volcano_tpu.utils import trace
 
 logger = logging.getLogger(__name__)
 
@@ -159,6 +160,8 @@ class Scheduler:
         # health through it too
         self.degrade = degrade_mod.default_ladder()
         self.last_profile: dict = {}
+        # sessions run so far: the step number of the next vt.session span
+        self.sessions = 0
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -340,27 +343,27 @@ class Scheduler:
         speculative session and leave the next cycle's solve dispatched.
         The serial run_once stays byte-for-byte available behind
         VOLCANO_TPU_PIPELINE=0 and the pipeline_disabled degrade rung."""
-        start = time.perf_counter()
-        info = self.pipeline_driver.run_cycle()
-        for name, ms in (info.get("action_ms") or {}).items():
-            metrics.update_action_duration(name, ms / 1e3)
-        metrics.update_e2e_duration(time.perf_counter() - start)
+        self.sessions += 1
+        with trace.step(self.sessions) as sp:
+            self.pipeline_driver.run_cycle()
+        metrics.update_e2e_duration(sp.elapsed)
 
     def run_once(self) -> None:
-        start = time.perf_counter()
-        self.load_conf()
+        self.sessions += 1
+        with trace.step(self.sessions) as sp:
+            self.load_conf()
 
-        ssn = open_session(self.cache, self.tiers)
-        try:
-            # fused whole-session dispatch when the session qualifies
-            # (ops/session_fuse.py), per-action loop otherwise
-            action_ms = run_actions(ssn, self.actions)
-            for name, ms in action_ms.items():
-                metrics.update_action_duration(name, ms / 1e3)
-        finally:
-            tpu = ssn.plugins.get("tpuscore")
-            # the device path's record of this cycle (mode, fallbacks,
-            # timings), read by chip_smoke.py
-            self.last_profile = dict(tpu.profile) if tpu is not None else {}
-            close_session(ssn)
-        metrics.update_e2e_duration(time.perf_counter() - start)
+            ssn = open_session(self.cache, self.tiers)
+            try:
+                # fused whole-session dispatch when the session qualifies
+                # (ops/session_fuse.py), per-action loop otherwise; each
+                # action records its own duration (framework.action_span)
+                run_actions(ssn, self.actions)
+            finally:
+                tpu = ssn.plugins.get("tpuscore")
+                # the device path's record of this cycle (mode, fallbacks,
+                # timings), read by chip_smoke.py
+                self.last_profile = dict(tpu.profile) if tpu is not None \
+                    else {}
+                close_session(ssn)
+        metrics.update_e2e_duration(sp.elapsed)

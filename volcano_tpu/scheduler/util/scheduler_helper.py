@@ -17,6 +17,7 @@ from volcano_tpu.api.job_info import TaskInfo
 from volcano_tpu.api.node_info import NodeInfo
 from volcano_tpu.api.unschedule_info import FitError, FitErrors, FitFailure
 from volcano_tpu.scheduler.options import server_opts
+from volcano_tpu.utils import trace
 
 BASELINE_PERCENTAGE_OF_NODES_TO_FIND = 50
 
@@ -57,17 +58,19 @@ def predicate_nodes(
 
     found: List[NodeInfo] = []
     processed = 0
-    for index in range(all_nodes):
-        node = nodes[(_last_processed_node_index + index) % all_nodes]
-        processed += 1
-        try:
-            fn(task, node)
-        except FitFailure as err:
-            fe.set_node_error(node.name, err.fit_error(task, node))
-            continue
-        found.append(node)
-        if len(found) >= num_to_find:
-            break
+    with trace.span("serial.predicate", nodes=all_nodes) as sp:
+        for index in range(all_nodes):
+            node = nodes[(_last_processed_node_index + index) % all_nodes]
+            processed += 1
+            try:
+                fn(task, node)
+            except FitFailure as err:
+                fe.set_node_error(node.name, err.fit_error(task, node))
+                continue
+            found.append(node)
+            if len(found) >= num_to_find:
+                break
+        sp.note(feasible=len(found))
 
     _last_processed_node_index = (_last_processed_node_index + processed) % all_nodes
     return found, fe
@@ -91,23 +94,24 @@ def prioritize_nodes(
 
     plugin_node_scores: Dict[str, Dict[str, float]] = {}
     node_order_scores: Dict[str, float] = {}
-    for node in nodes:
-        map_scores, order_score = map_fn(task, node)
-        for plugin, score in map_scores.items():
-            plugin_node_scores.setdefault(plugin, {})[node.name] = float(
-                math.floor(score)
-            )
-        node_order_scores[node.name] = order_score
-
-    reduce_scores = reduce_fn(task, plugin_node_scores)
-    batch_scores = batch_fn(task, nodes)
-
     node_scores: Dict[float, List[NodeInfo]] = {}
-    for node in nodes:
-        score = reduce_scores.get(node.name, 0.0)
-        score += node_order_scores.get(node.name, 0.0)
-        score += batch_scores.get(node.name, 0.0)
-        node_scores.setdefault(score, []).append(node)
+    with trace.span("serial.prioritize", nodes=len(nodes)):
+        for node in nodes:
+            map_scores, order_score = map_fn(task, node)
+            for plugin, score in map_scores.items():
+                plugin_node_scores.setdefault(plugin, {})[node.name] = float(
+                    math.floor(score)
+                )
+            node_order_scores[node.name] = order_score
+
+        reduce_scores = reduce_fn(task, plugin_node_scores)
+        batch_scores = batch_fn(task, nodes)
+
+        for node in nodes:
+            score = reduce_scores.get(node.name, 0.0)
+            score += node_order_scores.get(node.name, 0.0)
+            score += batch_scores.get(node.name, 0.0)
+            node_scores.setdefault(score, []).append(node)
     return node_scores
 
 

@@ -123,7 +123,7 @@ def _merge(base: Dict, override: Dict) -> Dict:
     return out
 
 
-def resolve_scenario_path(ref: str) -> str:
+def scenario_file(ref: str) -> str:
     """A path that exists wins; otherwise ``ref`` names a committed
     scenario (``cfg5_storm`` -> sim/scenarios/cfg5_storm.yaml)."""
     if os.path.exists(ref):
@@ -144,7 +144,7 @@ def list_scenarios() -> List[str]:
 
 
 def load_scenario(ref: str) -> Dict:
-    path = resolve_scenario_path(ref)
+    path = scenario_file(ref)
     with open(path) as fh:
         raw = yaml.safe_load(fh) or {}
     cfg = _merge(DEFAULTS, raw)

@@ -1,10 +1,9 @@
 """Device-interaction profiler: sync points, D2H fetches, overlap wall.
 
-Nothing in volcano_tpu ever fenced the device before PR 6: `dispatch_s` /
-`solve_s` windows conflated queueing with compute (jax dispatch is async on
-every backend), and the bench floor probe measured whatever the runtime
-happened to flush. This module is the ONE place host<->device
-synchronization happens so it can be counted:
+JAX dispatch is async on every backend, so a host clock read around a
+dispatch measures queueing, not compute. This module is the ONE place
+host<->device synchronization happens so it can be counted, and each wait
+is a ``vt.device.wait`` span (utils/trace.py):
 
 - ``start_fetch(x)`` begins the D2H copy immediately (``copy_to_host_async``
   when the array supports it) and returns a wait closure; the span between
@@ -29,6 +28,8 @@ import time
 from typing import Callable, List, Optional
 
 import numpy as np
+
+from volcano_tpu.utils import trace
 
 # the active collector (one scheduler session at a time); counters are
 # module-level so call sites need no plumbing through the action stack
@@ -100,7 +101,7 @@ def start_fetch(x) -> Callable[[], np.ndarray]:
     numpy/host arrays too (wait degenerates to np.asarray) so callers never
     need a backend check.
     """
-    t0 = time.perf_counter()
+    t0 = time.perf_counter()  # the overlap window opens at dispatch
     if _active is not None:
         _active["d2h_fetches"] += 1
     copy_async = getattr(x, "copy_to_host_async", None)
@@ -112,12 +113,12 @@ def start_fetch(x) -> Callable[[], np.ndarray]:
     _inflight.append(x)
 
     def wait() -> np.ndarray:
-        t1 = time.perf_counter()
-        out = np.asarray(x)
+        with trace.span("device.wait", kind="fetch") as sp:
+            out = np.asarray(x)
         if _active is not None:
             _active["sync_points"] += 1
-            _active["overlap_s"] += t1 - t0
-            _active["fence_wait_s"] += time.perf_counter() - t1
+            _active["overlap_s"] += sp.start - t0
+            _active["fence_wait_s"] += sp.elapsed
         _forget(x)
         return out
 
@@ -157,24 +158,26 @@ def fence(x=None) -> None:
     registered in-flight array. Placed only at profiling/apply boundaries —
     the overlap scheme depends on everything else staying async.
     """
-    t0 = time.perf_counter()
     blocked = False
     targets = [x] if x is not None else list(_inflight)
-    for t in targets:
-        block = getattr(t, "block_until_ready", None)
-        try:
-            if block is not None:
-                block()
-            else:
-                np.asarray(t)
-            blocked = True
-        except Exception:  # pragma: no cover - deleted/donated buffers
-            pass
-        if x is None:
-            _forget(t)
+    if not targets:
+        return
+    with trace.span("device.wait", kind="fence") as sp:
+        for t in targets:
+            block = getattr(t, "block_until_ready", None)
+            try:
+                if block is not None:
+                    block()
+                else:
+                    np.asarray(t)
+                blocked = True
+            except Exception:  # pragma: no cover - deleted/donated buffers
+                pass
+            if x is None:
+                _forget(t)
     if _active is not None and blocked:
         _active["sync_points"] += 1
-        _active["fence_wait_s"] += time.perf_counter() - t0
+        _active["fence_wait_s"] += sp.elapsed
 
 
 def drain() -> None:

@@ -14,6 +14,7 @@ from __future__ import annotations
 import os
 import random
 
+import numpy as np
 import pytest
 
 from tests.helpers import close_session, make_cache, make_tiers, open_session
@@ -400,3 +401,171 @@ def test_backfill_replay_budget_serial_fidelity(evict_on, monkeypatch):
         close_session(ssn2)
     if evict_on:
         assert "evict_backfill" in prof
+
+
+# ---------------------------------------------------------------------------
+# gang verdict over carried per-slot views (no job-table gather in a walk)
+# ---------------------------------------------------------------------------
+
+
+def _reference_gang_verdict(enc, st, claimees):
+    """The gather-based gang verdict: each call reads minAvailable and
+    ready from the job tables through vic_job."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    jv = enc["vic_job"]
+    min_av = enc["job_min_av"][jv]
+    budget0 = jnp.maximum(st["ready"][jv] - min_av, 0)
+
+    def body(v, carry):
+        used, out = carry
+        allow = (min_av[:, v] == 1) | (used[:, v] < budget0[:, v])
+        nominate = claimees[:, v] & allow
+        out = out.at[:, v].set(nominate)
+        upd = nominate[:, None] & enc["vic_samejob"][:, v, :]
+        return jnp.where(upd, used + 1, used), out
+
+    return lax.fori_loop(0, jv.shape[1], body, (
+        jnp.zeros(jv.shape, jnp.int32), jnp.zeros(jv.shape, bool)))[1]
+
+
+def _checked_machine(plan, monkeypatch):
+    """Run ``plan``'s machine with every gang verdict compared against the
+    reference and every statement discard checked. Returns (final state,
+    tallies over the run)."""
+    import jax
+    import jax.numpy as jnp
+
+    from volcano_tpu.ops import evict as evict_mod
+
+    tally = dict(calls=0, differ=0, zero_budget=0, unbudgeted=0,
+                 restored=0, stale_after_discard=0)
+
+    def note(names):
+        def cb(*vals):
+            for k, v in zip(names, vals):
+                tally[k] += int(v)
+        return cb
+
+    new_verdict, old_discard = evict_mod._gang_verdict, evict_mod._discard
+
+    def verdict(enc, st, claimees):
+        got = new_verdict(enc, st, claimees)
+        want = _reference_gang_verdict(enc, st, claimees)
+        jv = enc["vic_job"]
+        min_av = enc["job_min_av"][jv]
+        jax.debug.callback(
+            note(("calls", "differ", "zero_budget", "unbudgeted")),
+            1, jnp.sum(got != want),
+            jnp.sum(claimees & (min_av > 1) & (st["ready"][jv] <= min_av)),
+            jnp.sum(claimees & (min_av == 1)))
+        return got
+
+    def discard(enc, st, stmt_start):
+        out = old_discard(enc, st, stmt_start)
+        jax.debug.callback(
+            note(("restored", "stale_after_discard")),
+            jnp.sum(out["vic_ready"] - st["vic_ready"]),
+            jnp.sum(out["vic_ready"] != out["ready"][enc["vic_job"]]))
+        return out
+
+    monkeypatch.setattr(evict_mod, "_gang_verdict", verdict)
+    monkeypatch.setattr(evict_mod, "_discard", discard)
+    if plan.kind == "preempt":
+        machine, state0 = evict_mod.preempt_machine, evict_mod.preempt_state0
+    else:
+        machine, state0 = evict_mod.reclaim_machine, evict_mod.reclaim_state0
+    enc = {k: jnp.asarray(v) for k, v in plan.arrays.items()}
+    st = jax.jit(lambda e: machine(plan.spec, e, state0(e)))(enc)
+    jax.effects_barrier()
+    return jax.tree_util.tree_map(np.asarray, st), tally
+
+
+def _plan_after(kind, seed, monkeypatch):
+    """(session, plan) for ``kind`` on the seeded overcommit cluster once
+    the actions before it have run (allocate; preempt before reclaim)."""
+    from volcano_tpu.ops import evict as evict_mod
+
+    monkeypatch.setenv("VOLCANO_TPU_EVICT", "1")
+    ssn = open_session(_overcommit_cluster(seed),
+                       make_tiers(["tpuscore"], *TIER_SETS[0]))
+    get_action("allocate").execute(ssn)
+    if kind == "reclaim":
+        get_action("preempt").execute(ssn)
+    return ssn, evict_mod.build(ssn, kind)
+
+
+@pytest.mark.parametrize("seed", [0, 4, 8])
+@pytest.mark.parametrize("kind", ["preempt", "reclaim"])
+def test_gang_verdict_matches_job_table_reference(kind, seed, monkeypatch):
+    """The gang verdict read from the carried per-slot views equals the
+    job-table reference at every walk iteration, through budgets at zero,
+    unbudgeted (minAvailable == 1) gangs, cuts and (preempt) discarded
+    statements, and vic_ready ends equal to ready[vic_job]."""
+    ssn, plan = _plan_after(kind, seed, monkeypatch)
+    try:
+        assert plan is not None and not plan.trivial
+        assert "gang" in plan.spec.victim_fns
+        st, tally = _checked_machine(plan, monkeypatch)
+    finally:
+        close_session(ssn)
+    assert tally["calls"] > 0 and tally["differ"] == 0, tally
+    assert tally["zero_budget"] > 0 and tally["unbudgeted"] > 0, tally
+    np.testing.assert_array_equal(
+        st["vic_ready"], st["ready"][plan.arrays["vic_job"]])
+    evicted = plan.arrays["vic_valid"] & ~st["alive"]
+    assert evicted.any()
+    if kind == "preempt":
+        # a discarded statement gave its evictions back to vic_ready
+        assert tally["restored"] > 0, tally
+        assert tally["stale_after_discard"] == 0, tally
+
+
+def _slot_gathers(jaxpr, shape, in_loop=False, found=None):
+    """One flag per gather with output ``shape`` in ``jaxpr`` and the
+    sub-jaxprs of its eqns (while, scan, cond, jit): whether it runs
+    inside a while or scan body."""
+    from jax.extend import core as jcore
+
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "gather" and tuple(eqn.outvars[0].aval.shape) == shape:
+            found.append(in_loop)
+        inner = in_loop or name in ("while", "scan")
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (tuple, list)) else (val,):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jcore.Jaxpr):
+                    _slot_gathers(sub, shape, inner, found)
+    return found
+
+
+@pytest.mark.parametrize("tiers", range(len(TIER_SETS)))
+@pytest.mark.parametrize("kind", ["preempt", "reclaim"])
+def test_no_slot_gather_inside_machine_loops(kind, tiers, monkeypatch):
+    """No [N, V] gather (vic_job's shape) runs inside a loop of the evict
+    machines: the per-slot views are gathered once at machine entry."""
+    import jax
+
+    from volcano_tpu.ops import evict as evict_mod
+
+    monkeypatch.setenv("VOLCANO_TPU_EVICT", "1")
+    ssn = open_session(_overcommit_cluster(11),
+                       make_tiers(["tpuscore"], *TIER_SETS[tiers]))
+    try:
+        get_action("allocate").execute(ssn)
+        plan = evict_mod.build(ssn, kind)
+    finally:
+        close_session(ssn)
+    assert plan is not None and not plan.trivial
+    solve = evict_mod.solve_preempt if kind == "preempt" \
+        else evict_mod.solve_reclaim
+    jaxpr = jax.make_jaxpr(lambda e: solve(plan.spec, e))(plan.arrays)
+    shape = plan.arrays["vic_job"].shape
+    assert shape[0] != shape[1]  # the shape names vic_job's gathers alone
+    found = _slot_gathers(jaxpr.jaxpr, shape)
+    assert found.count(False) >= 1   # the entry gathers are seen
+    assert found.count(True) == 0, found

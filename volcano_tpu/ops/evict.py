@@ -372,11 +372,13 @@ def _gang_verdict(enc, st, claimees):
     """gang.go:82-86: per-job occupancy budget decremented per NOMINATED
     victim within one call — at most (ready - minAvailable) victims per
     gang per node row; minAvailable == 1 gangs are unbudgeted. Walked in
-    claimee order like the serial fn (victimview._gang_mask twin)."""
+    claimee order like the serial fn (victimview._gang_mask twin). Reads
+    the per-slot views (_slot_views), never the job tables: an [N, V]
+    gather per walk iteration is the walk's dearest op on the TPU."""
     jv = enc["vic_job"]
     v_width = jv.shape[1]
-    min_av = enc["job_min_av"][jv]                       # [N, V]
-    budget0 = jnp.maximum(st["ready"][jv] - min_av, 0)
+    min_av = enc["vic_min_av"]                           # [N, V]
+    budget0 = jnp.maximum(st["vic_ready"] - min_av, 0)
 
     def body(v, carry):
         used, out = carry
@@ -453,6 +455,30 @@ def _apply_evict_slot(enc, st, node, slot, active):
     return _log_append(st, OP_EVICT, node, slot, active)
 
 
+def _slot_views(enc, st):
+    """Machine entry, outside every loop: the per-slot views of the job
+    tables the gang verdict reads. ``vic_min_av`` is static; ``vic_ready``
+    is ``ready[vic_job]`` for every slot (pad slots included) and moves
+    with ``ready``: once per cut (_cut_ready) and once per undone eviction
+    (_discard). ``vic_job_t`` is vic_job with the node axis minor, the
+    layout the TPU gives [N, V] state: transposed here, once, rather than
+    relaid out inside every cut."""
+    jv = enc["vic_job"]
+    return (dict(enc, vic_min_av=enc["job_min_av"][jv], vic_job_t=jv.T),
+            dict(st, vic_ready=st["ready"][jv]))
+
+
+def _cut_ready(enc, st, node, alive_row):
+    """vic_ready after a cut at ``node``: each slot the cut evicted (alive
+    in ``alive_row`` before it, not after) takes one ready task from every
+    slot of its job, cluster-wide — one [V, V, N] compare-and-reduce."""
+    evicted = alive_row & ~st["alive"][node]
+    hits = (enc["vic_job_t"][None] == enc["vic_job"][node][:, None, None]) \
+        & evicted[:, None, None]
+    return dict(st, vic_ready=st["vic_ready"]
+                - jnp.sum(hits, axis=0, dtype=jnp.int32).T)
+
+
 def _apply_pipeline(enc, st, t, node):
     """Pipeline preemptor t onto node: PENDING -> PIPELINED (node add_task
     moves used/cnt; allocate handlers add to drf/proportion shares)."""
@@ -504,6 +530,8 @@ def _discard(enc, st, stmt_start):
         st["alive"] = st["alive"].at[node_e, slot].set(
             jnp.where(is_e, True, st["alive"][node_e, slot]))
         st["ready"] = st["ready"].at[jv].add(is_e.astype(jnp.int32))
+        st["vic_ready"] = st["vic_ready"] + (
+            is_e & (enc["vic_job"] == jv)).astype(jnp.int32)
         st["job_alloc"] = st["job_alloc"].at[jv].add(vreq)
         st["queue_alloc"] = st["queue_alloc"].at[qv].add(vreq)
         st["used"] = st["used"].at[node_p].add(-preq)
@@ -544,9 +572,10 @@ def _cut_preempt(enc, st, t, node, vmask):
         now = selp & jnp.all((need < got) | (jnp.abs(need - got) < eps))
         return st, got, covered | now
 
+    alive_row = st["alive"][node]
     st, _, covered = lax.fori_loop(
         0, v_width, body, (st, jnp.zeros_like(need), jnp.bool_(False)))
-    return st, covered
+    return _cut_ready(enc, st, node, alive_row), covered
 
 
 def _preempt_walk(spec: EvictSpec, enc, st, t, j, intra):
@@ -680,6 +709,7 @@ def preempt_machine(spec: EvictSpec, enc: dict, st: dict) -> dict:
     per-queue phase 1 (job heap pops, per-job statements, gang-pipelined
     commit/discard) then phase 2 (intra-job task-vs-task, per-task commit),
     interleaved per queue exactly as the host loop runs them."""
+    enc, st = _slot_views(enc, st)
     qp = enc["queue_real"].shape[0]
     ju = enc["under_jobs"].shape[0]
     t_total = enc["p_req"].shape[0]
@@ -853,9 +883,10 @@ def _cut_reclaim(enc, st, t, node, vmask):
         now = selp & _le2(need, got, eps)
         return st, got, covered | now
 
+    alive_row = st["alive"][node]
     st, _, covered = lax.fori_loop(
         0, v_width, body, (st, jnp.zeros_like(need), jnp.bool_(False)))
-    return st, covered
+    return _cut_ready(enc, st, node, alive_row), covered
 
 
 def _reclaim_walk(spec: EvictSpec, enc, st, t, j):
@@ -943,6 +974,7 @@ def reclaim_machine(spec: EvictSpec, enc: dict, st: dict) -> dict:
     """The whole reclaim action (reclaim.py execute) as one fused program:
     queue heap rotation (overused queues drop out un-re-pushed), one job
     pop and one task per queue visit, direct evict/pipeline ops."""
+    enc, st = _slot_views(enc, st)
     j_total = enc["job_prio"].shape[0]
     q_total = enc["queue_alloc0"].shape[0]
     t_total = enc["p_req"].shape[0]

@@ -14,6 +14,10 @@ is the one generator every configuration goes through (a seeded copy of
   feeds in production;
 - beside the cache it keeps a plain ``World`` (integers and names only) that
   the reference reads; the reference never looks at the program's objects.
+
+It reads ``namespace``, ``draw_seed``, ``nodes``, ``queues`` and ``groups``
+and ignores every other key of the file (``assumed``, ``guarantees``, the
+CPU rehearsal's ``tiny``; the modes read ``policy``).
 """
 
 from __future__ import annotations

@@ -67,6 +67,13 @@ class ControlSession:
                 "bind_times": {k: t for k, _, t in new}, "evicts": []}
 
 
+class NoDevicePath:
+    """The control has no device path to leave: a probe passes it."""
+
+    def of(self, profile: dict) -> dict:
+        return {}
+
+
 def install() -> None:
     """Put the control in every driver's session."""
     orig = traffic.Driver._session
@@ -76,6 +83,7 @@ def install() -> None:
         return orig(self, cl, sess, phase)
 
     traffic.Session = ControlSession
+    traffic.Fallbacks = NoDevicePath
     traffic.Driver._session = _session
 
 

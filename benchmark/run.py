@@ -12,9 +12,11 @@ adding files.
 
 One process, no child that touches JAX. In order: refuse anything but a TPU
 with enough chips; put JAX's persistent compile cache at a fixed path in
-the checkout; build the cell's cluster from ``--seed``; warm up the cell's
-own shapes; run sessions for ``--seconds``; check each session's output
-against the reference; print the result as the last line of stdout. With
+the checkout; build the cell's cluster from ``--seed``; run the mode's probe
+(a probe that leaves the device path ends the run: exit code 2, nothing
+printed); warm up the cell's own shapes; run sessions for ``--seconds``;
+check each session's output against the reference; print the result as
+the last line of stdout. With
 ``--trace 1`` the first few sessions of the window run under the profiler
 (for a mode whose window has no device work, from its probe session before
 the warm-up on) and the result carries the per-layer metrics instead of the
@@ -42,11 +44,9 @@ for _p in (HERE, ROOT):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
+from traffic import Refused  # noqa: E402
+
 CACHE_DIR = os.path.join(HERE, ".jax_cache")
-
-
-class Refused(Exception):
-    """No result can be measured here (exit code 2, nothing printed)."""
 
 
 def load_cell(root: str, name: str):
@@ -226,9 +226,12 @@ def main(argv=None, root: str = ROOT) -> int:
         print(f"benchmark: {e}; nothing measured", file=sys.stderr)
         return 2
     enable_compile_cache()
-
-    run = measure(cell, cfg, traffic, args.seed, args.seconds,
-                  bool(args.trace), devices, root)
+    try:
+        run = measure(cell, cfg, traffic, args.seed, args.seconds,
+                      bool(args.trace), devices, root)
+    except Refused as e:
+        print(f"benchmark: {e}; nothing measured", file=sys.stderr)
+        return 2
 
     metrics = {}
     for m in cell_metrics(bench, cell, bool(args.trace)):

@@ -26,12 +26,14 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
-# node count and per-class group counts of each configuration's tiny copy
-# (cfg4 keeps its 8000:5000:1750:250:500 proportions)
+# node count and per-class group counts of the tiny copies of the
+# configurations that came before the "tiny" entry (cfg4 keeps its
+# 8000:5000:1750:250:500 proportions); any other configuration states its
+# own as "tiny": {"nodes": n, "counts": [groups per class]}
 TINY = {"cfg5-full-default": (40, [40]),
         "cfg4-overcommit": (160, [100, 35, 5, 10])}
 TINY_TRAFFIC = {"warm_s": 2.0, "drain_s": 5.0, "probe_gangs": 16,
-                "rate_gangs_per_s": 2.0}
+                "path_probe_gangs": 16, "rate_gangs_per_s": 2.0}
 # tasks: the tiny backlog, preempt and probe sessions take the device path,
 # the open loop's other sessions (a few gangs each) stay serial, as at full
 # size
@@ -39,12 +41,41 @@ GATE = 96
 
 
 def shrink(cfg: dict) -> dict:
+    """The configuration's tiny copy, from its ``tiny`` entry or, for the
+    configurations that have none, its row of TINY."""
     cfg = json.loads(json.dumps(cfg))
-    nodes, counts = TINY[cfg["name"]]
+    if "tiny" in cfg:
+        nodes, counts = cfg["tiny"]["nodes"], cfg["tiny"]["counts"]
+    elif cfg["name"] in TINY:
+        nodes, counts = TINY[cfg["name"]]
+    else:
+        raise ValueError(
+            f"configuration {cfg['name']!r} has no \"tiny\" entry: the CPU "
+            "rehearsal needs \"tiny\": {\"nodes\": n, \"counts\": [groups "
+            "per class]} in its file")
+    if len(counts) != len(cfg["groups"]):
+        raise ValueError(
+            f"configuration {cfg['name']!r}: \"tiny\" gives {len(counts)} "
+            f"group counts for {len(cfg['groups'])} group classes")
     cfg["nodes"]["count"] = nodes
     for cls, n in zip(cfg["groups"], counts):
         cls["count"] = n
     return cfg
+
+
+def add_config(root, cfg: dict) -> None:
+    """A configuration's tiny copy, into the root's configs."""
+    (root / "benchmark" / "configs" / (cfg["name"] + ".json")).write_text(
+        json.dumps(shrink(cfg)))
+
+
+def add_traffic(root, name: str, tr: dict) -> None:
+    """A traffic mix, its lengths, rates and counts cut to the tiny size,
+    into the root's traffic."""
+    tr = dict(tr)
+    tr.update({k: v for k, v in TINY_TRAFFIC.items() if k in tr})
+    (root / "benchmark" / "traffic" / (name + ".json")).write_text(
+        json.dumps(tr))
 
 
 @pytest.fixture
@@ -64,13 +95,10 @@ def tiny_root(tmp_path, monkeypatch):
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
     for name in os.listdir(os.path.join(BENCH, "configs")):
         with open(os.path.join(BENCH, "configs", name)) as f:
-            cfg = json.load(f)
-        (base / "configs" / name).write_text(json.dumps(shrink(cfg)))
+            add_config(root, json.load(f))
     for name in os.listdir(os.path.join(BENCH, "traffic")):
         with open(os.path.join(BENCH, "traffic", name)) as f:
-            tr = json.load(f)
-        tr.update({k: v for k, v in TINY_TRAFFIC.items() if k in tr})
-        (base / "traffic" / name).write_text(json.dumps(tr))
+            add_traffic(root, name[:-len(".json")], json.load(f))
     monkeypatch.setattr(run, "check_device", lambda chips: jax.devices()[:chips])
     monkeypatch.setattr(run, "device_peaks", lambda kind: {})
     monkeypatch.setattr(run, "enable_compile_cache", lambda: None)

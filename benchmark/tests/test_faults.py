@@ -96,6 +96,7 @@ def test_control_is_not_correct(tiny_root, capsys, monkeypatch, cell):
     import traffic
 
     monkeypatch.setattr(traffic, "Session", traffic.Session)
+    monkeypatch.setattr(traffic, "Fallbacks", traffic.Fallbacks)
     monkeypatch.setattr(traffic.Driver, "_session", traffic.Driver._session)
     control.install()
     res = run_cell(tiny_root, cell, capsys=capsys)

@@ -7,9 +7,12 @@ so a new way of offering work is a new file. The rest of the traffic file
 is numbers the mode reads.
 
 Every mode draws sizes and times from fixed multisets that ``--seed`` only
-reorders, so every seed asks for the same work. Between sessions, outside
-their spans, a driver checks the session's output with the reference and
-collects garbage as the production loop does (``LowLatencyGC.maintain``).
+reorders, so every seed asks for the same work. A mode may run a probe
+session outside the window that has to take the device path; one that
+leaves it ends the run before the window (``Refused``: exit code 2,
+nothing printed). Between sessions, outside their spans, a driver checks
+the session's output with the reference and collects garbage as the
+production loop does (``LowLatencyGC.maintain``).
 """
 
 from __future__ import annotations
@@ -19,12 +22,16 @@ import os
 from typing import List, Optional
 
 from cluster import Cluster
-from harness import CompileCounter, Recorder, Session, new_cache
+from harness import CompileCounter, Fallbacks, Recorder, Session, new_cache
 from reference import CHECKS, check_session
 
 # the host span each phase's sessions run under; the device metrics read
 # only the window's ("bench.session")
 SPANS = {"window": "bench.session", "probe": "bench.probe"}
+
+
+class Refused(Exception):
+    """No result can be measured here (exit code 2, nothing printed)."""
 
 
 class Driver:
@@ -57,6 +64,10 @@ class Driver:
     @staticmethod
     def new_session(cache, recorder: Recorder, policy: str):
         return Session(cache, recorder, policy)
+
+    @staticmethod
+    def new_fallbacks():
+        return Fallbacks()
 
     def _session(self, cl: Cluster, sess, phase: str) -> dict:
         pending = sum(1 for t in cl.world.tasks.values() if not t.node)

@@ -13,10 +13,13 @@ from __future__ import annotations
 
 import random
 
+import numpy as np
+
 from tests.helpers import make_cache, make_tiers
 from tests.test_tpu_parity import DEFAULT_TIERS, gang_cluster
 from volcano_tpu.api import objects
 from volcano_tpu.api.resource import Resource
+from volcano_tpu.ops.encoder import _limbs
 from volcano_tpu.scheduler.framework import close_session, get_action, open_session
 from volcano_tpu.utils.jaxcompile import CompileWatcher
 from volcano_tpu.scheduler.util.test_utils import (
@@ -189,14 +192,16 @@ class TestInt32OverflowExactness:
             "res_unit": jnp.array([1.0]),
             "eps": jnp.array([10.0]),
             "task_req": jnp.full((t, 1), float(req)),
-            "queue_deserved": jnp.array([[2.0e9]]),
+            # deserved 2e9 plus eps 10, as encoder limbs
+            "queue_bound_limbs": jnp.asarray(
+                _limbs(np.array([[2.0e9 + 10]]))),
         }
         accept = jnp.ones(t, bool)
         task_rank = jnp.arange(t, dtype=jnp.int32)
         task_queue = jnp.zeros(t, jnp.int32)
         task_job = jnp.arange(t, dtype=jnp.int32)  # one job per task
-        out = R._queue_budget(enc, jnp.zeros((1, 1)), accept,
-                              task_rank, task_queue, task_job)
+        out, _ = R._queue_budget(enc, jnp.zeros((1, 1, 2), jnp.int32),
+                                 accept, task_rank, task_queue, task_job)
         got = int(jnp.sum(out))
         # jobs 0..55 see alloc_before = k*36e6 < 2e9 + 10; job 56 is the
         # first over; a wrapped cumsum would re-admit jobs >= 60

@@ -68,7 +68,7 @@ def _session(cache, mode: str) -> dict:
 
 def _traced(out_dir, fn):
     """(fn's result, [(name, start ns, end ns)] of the vt.* spans in the
-    .xplane.pb a capture around fn wrote)."""
+    .xplane.pb a capture around fn wrote, {name: [counts of each span]})."""
     import jax
     from jax.profiler import ProfileData
 
@@ -82,14 +82,15 @@ def _traced(out_dir, fn):
         jax.profiler.stop_trace()
     (path,) = glob.glob(os.path.join(str(out_dir), "**", "*.xplane.pb"),
                         recursive=True)
-    spans = []
+    spans, counts = [], collections.defaultdict(list)
     for plane in ProfileData.from_file(path).planes:
         for line in plane.lines:
             for ev in line.events:
                 if ev.name.startswith(trace.PREFIX):
                     start = int(ev.start_ns)
                     spans.append((ev.name, start, start + int(ev.duration_ns)))
-    return out, sorted(spans, key=lambda s: s[1])
+                    counts[ev.name].append(dict(ev.stats))
+    return out, sorted(spans, key=lambda s: s[1]), counts
 
 
 def _inside(spans, name, parent):
@@ -120,8 +121,8 @@ def _counts(fn) -> collections.Counter:
 
 def test_span_tree_of_a_device_session(tmp_path):
     _session(_cluster(40, 24), "rounds")  # compile outside the capture
-    prof, spans = _traced(tmp_path,
-                          lambda: _session(_cluster(40, 24), "rounds"))
+    prof, spans, counts = _traced(
+        tmp_path, lambda: _session(_cluster(40, 24), "rounds"))
     assert prof["mode"] == "rounds", prof.get("fallback")
     names = {n for n, _, _ in spans}
     for leaf in ("vt.encode", "vt.h2d", "vt.replica.store",
@@ -130,6 +131,17 @@ def test_span_tree_of_a_device_session(tmp_path):
     for child in ("vt.apply.prep", "vt.apply.loop", "vt.apply.bind",
                   "vt.apply.post"):
         assert _inside(spans, child, "vt.apply"), child
+    # the encoder's node matrices and per-node bound check
+    assert _inside(spans, "vt.encode.nodes", "vt.encode")
+    assert [c["nodes"] for c in counts["vt.encode.nodes"]] == [40]
+    # the rounds solve's dispatch, to its fetched packed result: int16 up
+    # to 32,766 nodes (tasks, node mask and profile tail)
+    (hop,) = [c for c in counts["vt.dispatch"] if "rounds" in c]
+    assert hop["rounds"] == prof["rounds"] > 0
+    assert hop["full_sweeps"] == prof["full_sweep_rounds"]
+    assert hop["window_k"] == prof["window_k"]
+    assert hop["d2h_bytes"] > 2 * (40 + 192)
+    assert hop["d2h_bytes"] % 2 == 0
     assert {"vt.open.snapshot", "vt.open.plugin.tpuscore",
             "vt.action.enqueue", "vt.action.backfill", "vt.dispatch",
             "vt.close.flush_mirror", "vt.close.job_updater",
@@ -144,6 +156,7 @@ def test_device_path_span_count_does_not_grow_with_nodes():
     small = _counts(lambda: _session(_cluster(40, 24), "rounds"))
     large = _counts(lambda: _session(_cluster(160, 24), "rounds"))
     assert small["vt.action.allocate"] == 1
+    assert small["vt.encode.nodes"] == small["vt.dispatch"] == 1
     assert small == large
 
 

@@ -283,12 +283,11 @@ def _full_default(c: SchedulerCache, scale: float) -> int:
 
 
 def _paper_2x(c: SchedulerCache, scale: float) -> int:
-    """cfg7: the paper-2x standing config — 100k tasks x 50k nodes under
-    the full default conf (ROADMAP item 3). Twice the paper's 50k x 10k
-    north star on BOTH axes the mesh shards over, so the per-device-count
-    scaling curve (bench.py --mesh 1,2,4 -> tpu_mesh_curve) is measured
-    against a cluster one chip cannot own: at 8 devices each shard still
-    carries a cfg5-sized node slice."""
+    """cfg7: 100k tasks x 50k nodes under the full default conf, twice
+    the paper's 50k x 10k north star on both axes. Its memory, 50k x 64Gi
+    = 3.28e9 MiB in all, is past 2^31 quantized units; each node is far
+    inside them (the encoder's per-node bound). The benchmark runs it on
+    one chip as benchmark/configs/cfg7-paper-2x.json (cell cfg7.backlog)."""
     rng = random.Random(7)
     tasks, nodes = max(int(100000 * scale), 24), max(int(50000 * scale), 8)
     groups = tasks // 8

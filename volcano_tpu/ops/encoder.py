@@ -41,6 +41,7 @@ from volcano_tpu.ops.kernels import SolveSpec
 from volcano_tpu.scheduler import conf
 from volcano_tpu.scheduler.plugins import nodeorder as nodeorder_mod
 from volcano_tpu.scheduler.plugins import predicates as predicates_mod
+from volcano_tpu.utils import trace
 
 SUPPORTED_JOB_ORDER = ("priority", "gang", "drf")
 SUPPORTED_QUEUE_ORDER = ("proportion",)
@@ -160,6 +161,25 @@ def _conf_arrays(R: int) -> tuple:
                             np.float64)
         cached = _CONF_ARRAYS[R] = (eps, is_scalar, res_unit)
     return cached
+
+
+def _limbs(q: np.ndarray) -> np.ndarray:
+    """Non-negative whole numbers below 2^46 (float64) as int32 limb pairs
+    on a trailing axis: [..., 0] = q >> 15, [..., 1] = q & 0x7FFF."""
+    q = q.astype(np.int64)
+    return np.stack([q >> 15, q & 0x7FFF], -1).astype(np.int32)
+
+
+def queue_limbs(deserved: np.ndarray, allocated: np.ndarray,
+                eps: np.ndarray, res_unit: np.ndarray):
+    """The rounds kernel's proportion bounds [Q, R] as int32 limb pairs
+    [Q, R, 2] (rounds._queue_over): floor(deserved) + eps and
+    ceil(allocated), in quantized units. Quantized here in float64: the
+    chip's float32 would round a 1e14-byte share to 8 MiB. The bound is
+    clipped below 2^46, past any total the kernel can reach."""
+    bound = np.clip(np.floor(deserved / res_unit) + eps / res_unit,
+                    0, 2.0**46 - 1)
+    return _limbs(bound), _limbs(np.ceil(allocated / res_unit))
 
 
 def _qualifying_anti_terms(pod, batch_on: bool):
@@ -997,25 +1017,28 @@ def encode_session(ssn, allow_residue: bool = False) -> EncodedSnapshot:
                 (r.scalar_resources or {}).get(rn, 0.0) for r in ress]
         return m
 
-    node_idle = _node_matrix("idle")
-    node_used = _node_matrix("used")
-    node_alloc = _node_matrix("allocatable")
-
-    # int32 bound safety for the rounds kernel: segment accumulators are
-    # limb-exact below 2^46 quantized units (rounds._seg_limbs), but the
-    # quantized BOUNDS (per-node idle, per-queue deserved/allocated — all
-    # <= cluster totals) are plain int32; a cluster whose per-dimension
-    # total exceeds 2^31 quantized units would wrap them, so fall back
-    # honestly instead
-    if node_alloc.size:
-        total_q = node_alloc.sum(axis=0) / res_unit
-        if float(total_q.max()) >= 2.0**31 - 2.0**20:
-            raise EncoderFallback(
-                "cluster capacity exceeds int32 quantized-bound range "
-                f"({total_q.max():.3g} units)")
-    # ... and the limb accumulators sum REQUESTS (accepted or not), so the
-    # total quantized pending request per dimension must stay under their
-    # 2^46 exactness envelope
+    with trace.span("encode.nodes", nodes=n_count):
+        node_idle = _node_matrix("idle")
+        node_used = _node_matrix("used")
+        node_alloc = _node_matrix("allocatable")
+        # int32 bound safety for the rounds kernel: segment accumulators
+        # and the queue bounds are limb-exact below 2^46 quantized units
+        # (rounds._seg_limbs, rounds._row_limbs), but a node's quantized
+        # idle is a plain int32 bound (rounds._resolve); a node whose
+        # capacity reaches 2^31 quantized units (2 PiB of memory) would
+        # wrap it, so fall back honestly instead
+        if node_alloc.size:
+            node_q = float((np.maximum(node_alloc.max(axis=0),
+                                       node_idle.max(axis=0))
+                            / res_unit).max())
+            if node_q >= 2.0**31 - 2.0**20:
+                raise EncoderFallback(
+                    "a node's capacity exceeds the int32 quantized-bound "
+                    f"range ({node_q:.3g} units)")
+    # the limb accumulators sum REQUESTS (accepted or not), so the total
+    # quantized pending request per dimension must stay under their 2^46
+    # exactness envelope
+    total_req_q = np.zeros(R)
     if task_req.size:
         req_q = np.ceil(task_req / res_unit[None, :])
         if float(req_q.max()) >= 2.0**31:
@@ -1069,6 +1092,15 @@ def encode_session(ssn, allow_residue: bool = False) -> EncodedSnapshot:
             queue_alloc0[qi] = _resource_vec(attr.allocated, rnames)
             present = {"cpu", "memory", *(attr.deserved.scalar_resources or {})}
             queue_present[qi] = [rn in present for rn in rnames]
+    # the rounds kernel carries a queue's allocation in limbs; it grows by
+    # at most the pending request, which must keep it in the 2^46 range
+    if float((np.ceil(queue_alloc0 / res_unit).max(axis=0)
+              + total_req_q).max()) >= 2.0**46:
+        raise EncoderFallback(
+            "queue allocation plus pending request exceeds the limb-exact "
+            "range")
+    queue_bound_limbs, queue_alloc0_limbs = queue_limbs(
+        queue_deserved, queue_alloc0, eps, res_unit)
 
     # ---- job arrays --------------------------------------------------------
     job_queue = np.array([q_index[j.queue] for j in jobs], np.int32) if jobs else np.zeros(0, np.int32)
@@ -1207,6 +1239,8 @@ def encode_session(ssn, allow_residue: bool = False) -> EncodedSnapshot:
         queue_deserved=queue_deserved,
         queue_present=queue_present,
         queue_alloc0=queue_alloc0,
+        queue_bound_limbs=queue_bound_limbs,
+        queue_alloc0_limbs=queue_alloc0_limbs,
         queue_tie_rank=np.arange(q_count, dtype=np.int32),
         q_in_ns0=q_in_ns,
         ns_active0=np.array([i < len(ns_names) for i in range(ns_count)]),

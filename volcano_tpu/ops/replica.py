@@ -94,7 +94,7 @@ FAMILIES: Dict[str, tuple] = {
     "node": ("node_idle", "node_used", "node_alloc", "node_cnt",
              "node_max_tasks"),
     "job": ("job_ready_base", "job_alloc0", "job_active0"),
-    "queue": ("queue_deserved", "queue_alloc0"),
+    "queue": ("queue_bound_limbs", "queue_alloc0_limbs"),
     "ns": ("ns_alloc0", "ns_active0"),
 }
 

@@ -407,8 +407,8 @@ def _seg_limbs(req_s, start_idx):
     alone reaches 2^31 after ~2^16 max-size rows), so the prefix sums are
     built with a carry-normalizing associative scan: every partial keeps
     lo in [0, 2^15), and hi holds total>>15 — within int32 for any prefix
-    total < 2^46 (70 billion cores / 64 EiB; the encoder gates totals far
-    below that)."""
+    total < 2^46 (70 billion cores / 64 EiB; the encoder falls back when
+    the pending request, or a queue's allocation plus it, reaches that)."""
 
     def combine(a, b):
         ah, al = a
@@ -434,9 +434,57 @@ def _limbs_lt(seg_hi, seg_lo, bound):
     """Exact (seg_hi*2^15 + seg_lo) < bound for non-negative limb pairs;
     bounds <= 0 compare false (nothing non-negative is below them)."""
     b = jnp.maximum(bound, 0)
-    b_hi = b >> 15
-    b_lo = b & 0x7FFF
-    return (seg_hi < b_hi) | ((seg_hi == b_hi) & (seg_lo < b_lo))
+    return _pair_lt(seg_hi, seg_lo, b >> 15, b & 0x7FFF)
+
+
+def _pair_lt(a_hi, a_lo, b_hi, b_lo):
+    """Exact a < b for normalized limb pairs (lo in [0, 2^15))."""
+    return (a_hi < b_hi) | ((a_hi == b_hi) & (a_lo < b_lo))
+
+
+# queue bounds and the carried queue allocation are int32 limb pairs
+# stacked on a trailing axis: [..., 0] = value >> 15, [..., 1] = value &
+# 0x7FFF. A queue's deserved share may be as large as the cluster (past
+# 2^31 MiB on 50k nodes of 64Gi), and float32 would round a 1e14-byte bound
+# to 8 MiB; the encoder quantizes the bounds in float64 and ships limbs
+
+
+def _limbs_add(a, d):
+    """a + d for [..., 2] normalized limb pairs."""
+    lo = a[..., 1] + d[..., 1]
+    return jnp.stack([a[..., 0] + d[..., 0] + (lo >> 15), lo & 0x7FFF], -1)
+
+
+def _limbs_sub(a, d):
+    """a - d for [..., 2] normalized limb pairs, a >= d."""
+    lo = a[..., 1] - d[..., 1]
+    borrow = (lo < 0).astype(jnp.int32)
+    return jnp.stack([a[..., 0] - d[..., 0] - borrow, lo + (borrow << 15)], -1)
+
+
+def _limbs_sum(req_i, mask):
+    """Exact sum of the masked non-negative int32 requests [T, R] as
+    normalized limb pairs [R, 2].
+
+    Each request is split into 17, 7 and 8 bits before the sum, so no
+    partial sum wraps for T < 2^23 rows and totals below 2^46; the carries
+    are then folded back into two 15-bit limbs."""
+    v = jnp.where(mask[:, None], req_i, 0)
+    hi, mid, low = (jnp.sum(x, axis=0, dtype=jnp.int32)
+                    for x in (v >> 15, (v >> 8) & 0x7F, v & 0xFF))
+    mid = mid + (low >> 8)
+    return jnp.stack([hi + (mid >> 7), ((mid & 0x7F) << 8) | (low & 0xFF)],
+                     -1)
+
+
+def _queue_over(queue_alloc, bound, is_scalar):
+    """[Q] bool: the queue's allocation is not below deserved + eps in some
+    dimension (the negation of Resource.less_equal, proportion.go:201),
+    exactly, on limb pairs."""
+    hi, lo = queue_alloc[..., 0], queue_alloc[..., 1]
+    le = _pair_lt(hi, lo, bound[..., 0], bound[..., 1])
+    skip = is_scalar[None, :] & (hi == 0) & (lo <= MIN_MILLI_SCALAR)
+    return ~jnp.all(le | skip, axis=-1)
 
 
 def _resolve(spec: SolveSpec, enc, idle, cnt, choice, task_rank):
@@ -501,13 +549,15 @@ def _queue_budget(enc, queue_alloc, accept, task_rank, task_queue, task_job):
     (proportion.go:201-212 + allocate.go:134-146). Reproduce that here: for
     accepted tasks ordered (queue, rank), a job's tasks survive iff
     queue_alloc + contributions of higher-ranked jobs in the same queue
-    fit under deserved with the epsilon comparison.
+    fit under deserved with the epsilon comparison. ``queue_alloc`` [Q, R,
+    2] and ``enc["queue_bound_limbs"]`` (deserved + eps) are limb pairs.
+    Returns the surviving accept mask [T] and each queue's admitted
+    request [Q, R, 2], exact, for the carried allocation.
     """
     t_total = accept.shape[0]
     is_scalar = enc["is_scalar"]
     # same exact two-limb int32 units as _resolve (see _seg_limbs)
     unit = enc["res_unit"]
-    eps_i = (enc["eps"] / unit).astype(jnp.int32)
     req_i = jnp.ceil(enc["task_req"] / unit[None, :]).astype(jnp.int32)
     req = jnp.where(accept[:, None], req_i, 0)
 
@@ -534,19 +584,34 @@ def _queue_budget(enc, queue_alloc, accept, task_rank, task_queue, task_job):
     before_hi = jnp.where(job_at_queue_start, 0, seg_hi[prev])
     before_lo = jnp.where(job_at_queue_start, 0, seg_lo[prev])
 
-    alloc_i = jnp.ceil(queue_alloc / unit[None, :]).astype(jnp.int32)
-    deserved_i = jnp.floor(enc["queue_deserved"] / unit[None, :]).astype(jnp.int32)
     # total = queue_alloc + higher-ranked same-queue jobs, as limbs
-    a = alloc_i[q_s]
-    tot_lo = before_lo + (a & 0x7FFF)
-    tot_hi = before_hi + (a >> 15) + (tot_lo >> 15)
-    tot_lo = tot_lo & 0x7FFF
-    le = _limbs_lt(tot_hi, tot_lo, deserved_i[q_s] + eps_i[None, :])
+    tot = _limbs_add(queue_alloc[q_s], jnp.stack([before_hi, before_lo], -1))
+    tot_hi, tot_lo = tot[..., 0], tot[..., 1]
+    bound = enc["queue_bound_limbs"][q_s]
+    le = _pair_lt(tot_hi, tot_lo, bound[..., 0], bound[..., 1])
     skip = is_scalar[None, :] & (tot_hi == 0) & (tot_lo <= MIN_MILLI_SCALAR)
     ok = jnp.all(le | skip, axis=-1)
-
+    # tot only grows along a queue's rows, so the admitted jobs are a
+    # prefix of each queue segment (the serial loop stops a queue at its
+    # first job over deserved); held as a prefix here, the queue's
+    # admitted total is the within-queue cumsum at the prefix's last row
+    bad = jnp.cumsum((~ok).astype(jnp.int32))
+    bad_base = jnp.where(q_base_idx > 0, bad[jnp.maximum(q_base_idx - 1, 0)],
+                         0)
+    ok = bad == bad_base
+    cnt = jnp.cumsum(ok.astype(jnp.int32))
+    q_ids = jnp.arange(queue_alloc.shape[0], dtype=q_s.dtype)
+    first = jnp.searchsorted(q_s, q_ids, side="left")
+    end = jnp.searchsorted(q_s, q_ids, side="right")
+    n_ok = jnp.where(
+        end > first,
+        cnt[jnp.maximum(end - 1, 0)]
+        - jnp.where(first > 0, cnt[jnp.maximum(first - 1, 0)], 0), 0)
+    last = jnp.clip(first + n_ok - 1, 0, t_total - 1)
+    admitted = jnp.where((n_ok > 0)[:, None, None],
+                         jnp.stack([seg_hi[last], seg_lo[last]], -1), 0)
     accept_s = accept[order] & ok
-    return jnp.zeros(t_total, bool).at[order].set(accept_s)
+    return jnp.zeros(t_total, bool).at[order].set(accept_s), admitted
 
 
 def unpack_layout(layout, bufs):
@@ -653,6 +718,9 @@ def solve_rounds(spec: SolveSpec, enc: dict):
         & enc["job_active0"][task_job]
 
     max_tasks_per_job = jnp.int32(t_total)
+    # quantized requests for the exact queue-allocation carry
+    req_q = jnp.ceil(enc["task_req"] / enc["res_unit"][None, :]).astype(
+        jnp.int32)
 
     st = dict(
         idle=enc["node_idle"], used=enc["node_used"],
@@ -661,7 +729,6 @@ def solve_rounds(spec: SolveSpec, enc: dict):
         active=task_valid,
         job_placed=jnp.zeros(j_total, jnp.int32),
         job_alloc=enc["job_alloc0"],
-        queue_alloc=enc["queue_alloc0"],
         ns_alloc=enc["ns_alloc0"],
         rounds=jnp.int32(0),
         progress=jnp.bool_(True),
@@ -687,6 +754,10 @@ def solve_rounds(spec: SolveSpec, enc: dict):
     )
     if spec.use_exclusion:
         st["excl_occ"] = enc["excl_occ0"]
+    if spec.use_prop_overused:
+        # exact quantized units, as limb pairs: read only by the overused
+        # gate and the queue budget
+        st["queue_alloc"] = enc["queue_alloc0_limbs"]
     # stall pairs cost two rounds per placement or rollback in the worst
     # case, so the runaway bound is 2(T+J)+8 (see outer_body)
     round_budget = 2 * (t_total + j_total) + 8
@@ -697,8 +768,8 @@ def solve_rounds(spec: SolveSpec, enc: dict):
 
         active = st["active"]
         if spec.use_prop_overused:
-            over = ~_le_eps_rows(st["queue_alloc"], enc["queue_deserved"],
-                                 enc["eps"], enc["is_scalar"])
+            over = _queue_over(st["queue_alloc"], enc["queue_bound_limbs"],
+                               enc["is_scalar"])
             active = active & ~over[task_queue]
 
         idle, used, cnt = st["idle"], st["used"], st["cnt"]
@@ -830,8 +901,8 @@ def solve_rounds(spec: SolveSpec, enc: dict):
             choice = jnp.where(keepm, choice, -1)
         accept = _resolve(spec, enc, st["idle"], st["cnt"], choice, task_rank)
         if spec.use_prop_overused:
-            accept = _queue_budget(enc, st["queue_alloc"], accept,
-                                   task_rank, task_queue, task_job)
+            accept, admitted = _queue_budget(enc, st["queue_alloc"], accept,
+                                             task_rank, task_queue, task_job)
 
         node = jnp.clip(choice, 0, st["idle"].shape[0] - 1)
         dreq = jnp.where(accept[:, None], enc["task_req"], 0.0).astype(dt)
@@ -845,6 +916,8 @@ def solve_rounds(spec: SolveSpec, enc: dict):
             st = dict(st, excl_occ=st["excl_occ"].at[
                 jnp.maximum(task_excl, 0), node].max(
                     accept & (task_excl >= 0)))
+        if spec.use_prop_overused:
+            st = dict(st, queue_alloc=_limbs_add(st["queue_alloc"], admitted))
         capped = st["capped"]
         if spec.round_min_progress > 1:
             # diminishing-returns exit: a nonzero round below the progress
@@ -866,7 +939,6 @@ def solve_rounds(spec: SolveSpec, enc: dict):
             active=st["active"] & ~accept,
             job_placed=st["job_placed"].at[task_job].add(accept.astype(jnp.int32)),
             job_alloc=st["job_alloc"].at[task_job].add(dreq),
-            queue_alloc=st["queue_alloc"].at[task_queue].add(dreq),
             ns_alloc=st["ns_alloc"].at[task_ns].add(dreq),
             rounds=st["rounds"] + 1,
             progress=any_accept,
@@ -903,6 +975,11 @@ def solve_rounds(spec: SolveSpec, enc: dict):
             st = dict(st, excl_occ=st["excl_occ"].at[
                 jnp.maximum(task_excl, 0), node].min(
                     ~(roll & (task_excl >= 0))))
+        if spec.use_prop_overused:
+            # the rolled-back job's tasks all sit in its queue
+            q = enc["job_queue"][worst]
+            st = dict(st, queue_alloc=st["queue_alloc"].at[q].set(_limbs_sub(
+                st["queue_alloc"][q], _limbs_sum(req_q, roll))))
         return dict(
             st,
             idle=st["idle"].at[node].add(dreq),
@@ -912,7 +989,6 @@ def solve_rounds(spec: SolveSpec, enc: dict):
             active=st["active"] & ~dead_task,
             job_placed=jnp.where(roll_job, 0, st["job_placed"]),
             job_alloc=st["job_alloc"].at[task_job].add(-dreq),
-            queue_alloc=st["queue_alloc"].at[task_queue].add(-dreq),
             ns_alloc=st["ns_alloc"].at[task_ns].add(-dreq),
             progress=jnp.bool_(True),
             dead=~jnp.any(cand),
@@ -1003,8 +1079,8 @@ def solve_rounds(spec: SolveSpec, enc: dict):
                 # visits); their tasks stay ACTIVE so the capped -2 marking
                 # below still routes them to the serial residue retry,
                 # exactly as the pre-tail capped exit did
-                over = ~_le_eps_rows(s["queue_alloc"], enc["queue_deserved"],
-                                     enc["eps"], enc["is_scalar"])
+                over = _queue_over(s["queue_alloc"], enc["queue_bound_limbs"],
+                                   enc["is_scalar"])
                 eligible = eligible & ~over[task_queue]
             # lexicographic argmin over the SAME job-order keys _job_rank
             # sorts by, without the per-step [J] lexsort (sorts are the
@@ -1078,7 +1154,6 @@ def solve_rounds(spec: SolveSpec, enc: dict):
                 job_placed=s["job_placed"].at[task_job[t]].add(
                     ok.astype(jnp.int32)),
                 job_alloc=s["job_alloc"].at[task_job[t]].add(dreq),
-                queue_alloc=s["queue_alloc"].at[task_queue[t]].add(dreq),
                 ns_alloc=s["ns_alloc"].at[task_ns[t]].add(dreq),
                 tail_steps=s["tail_steps"] + 1,
                 tail_placed=s["tail_placed"] + ok.astype(jnp.int32),
@@ -1087,6 +1162,11 @@ def solve_rounds(spec: SolveSpec, enc: dict):
                 out["excl_occ"] = s["excl_occ"].at[
                     jnp.maximum(task_excl[t], 0), node].max(
                         ok & (task_excl[t] >= 0))
+            if spec.use_prop_overused:
+                r = jnp.where(ok, req_q[t], 0)
+                q = task_queue[t]
+                out["queue_alloc"] = s["queue_alloc"].at[q].set(_limbs_add(
+                    s["queue_alloc"][q], jnp.stack([r >> 15, r & 0x7FFF], -1)))
             return out
 
         s = dict(st, tail_steps=jnp.int32(0), tail_stuck=jnp.bool_(False),
@@ -1131,9 +1211,3 @@ def solve_rounds(spec: SolveSpec, enc: dict):
     return (assign, st["rounds"], st.get("tail_placed", jnp.int32(0)),
             full_sweeps, st["capped"], placed_hist, touched)
 
-
-def _le_eps_rows(l, r, eps, is_scalar):
-    """Rowwise Resource.less_equal for [Q, R] pairs."""
-    le = l < r + eps[None, :]
-    skip = is_scalar[None, :] & (l <= MIN_MILLI_SCALAR)
-    return jnp.all(le | skip, axis=-1)

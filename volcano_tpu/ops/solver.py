@@ -114,11 +114,13 @@ _NODE_AXIS = {
 }
 
 # arrays the rounds kernel never reads: per-task columns it re-derives from
-# the class arrays on device (rounds.solve_rounds), plus the parity scan's
-# sampling-window inputs — excluded from the rounds host->device transfer
+# the class arrays on device (rounds.solve_rounds), the parity scan's
+# sampling-window inputs, and the float queue bounds the rounds kernel
+# reads as limbs instead — excluded from the rounds host->device transfer
 _ROUNDS_SKIP = frozenset({
     "task_req", "task_initreq", "task_nz_cpu", "task_nz_mem",
     "task_sig", "task_has_pod", "node_real", "real_n",
+    "queue_deserved", "queue_alloc0",
 })
 
 
@@ -680,11 +682,17 @@ class BatchAllocator:
                 # groups (unpack_layout merges them), so the sharded
                 # session is byte-for-byte the single-device program over
                 # identical values
-                with trace.span("dispatch"):
+                with trace.span("dispatch") as sp:
                     wait = devprof.start_fetch(rounds_mod.solve_rounds_packed(
                         prep["spec"], prep["layout"], prep["staged"]))
-                out = wait()
-                assign, meta = self.parse_packed(out)
+                    out = wait()
+                    assign, meta = self.parse_packed(out)
+                    # the packed result is int16 up to 32,766 nodes, int32
+                    # past them (rounds.pack_result)
+                    sp.note(rounds=meta["n_rounds"],
+                            full_sweeps=meta["full_sweeps"],
+                            window_k=prep["spec"].window_k,
+                            d2h_bytes=int(out.nbytes))
                 assign = np.asarray(assign)
             else:
                 with trace.span("dispatch", mode=mode):

@@ -477,7 +477,8 @@ def _checked_machine(plan, monkeypatch):
     else:
         machine, state0 = evict_mod.reclaim_machine, evict_mod.reclaim_state0
     enc = {k: jnp.asarray(v) for k, v in plan.arrays.items()}
-    st = jax.jit(lambda e: machine(plan.spec, e, state0(e)))(enc)
+    st = jax.jit(
+        lambda e: machine(plan.spec, e, state0(plan.spec, e)))(enc)
     jax.effects_barrier()
     return jax.tree_util.tree_map(np.asarray, st), tally
 

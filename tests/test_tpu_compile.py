@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import re
 import time
 
 import jax
@@ -92,8 +93,8 @@ def capture(targets, stop):
 
 
 def compile_for(sharding, fn, args, kw):
-    """(seconds, memory_analysis) of compiling ``fn`` for ``sharding``'s
-    device over the abstract shapes of the captured arguments."""
+    """(seconds, compiled) of compiling ``fn`` for ``sharding``'s device
+    over the abstract shapes of the captured arguments."""
     def abstract(x):
         if isinstance(x, (np.ndarray, jax.Array, jax.ShapeDtypeStruct)):
             assert x.dtype not in (np.float64, np.int64), x.dtype
@@ -103,7 +104,7 @@ def compile_for(sharding, fn, args, kw):
     args, kw = jax.tree_util.tree_map(abstract, (args, kw))
     t0 = time.perf_counter()
     compiled = fn.lower(*args, **kw).compile()
-    return time.perf_counter() - t0, compiled.memory_analysis()
+    return time.perf_counter() - t0, compiled
 
 
 def _session(cfg, scale):
@@ -162,8 +163,57 @@ def express_call(n_nodes, batch=8):
     return place.solve_express, args, {}
 
 
+def loop_relayouts(hlo, shapes):
+    """The copies inside any while loop of a compiled program's text whose
+    result has one of ``shapes`` (such as "s32[4096]") and a layout other
+    than their operand's, memory space aside: "name: from -> to"."""
+    comps, defs, cur = {}, {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None and line.startswith(" "):
+            cur.append(line)
+            d = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (\w+\[[\d,]*\])"
+                         r"(\{[^}]*\})?", line)
+            if d:
+                defs[d.group(1)] = (d.group(2), d.group(3) or "")
+
+    def callees(line):
+        named = re.findall(r"(?:body|condition|to_apply|calls|"
+                           r"true_computation|false_computation)="
+                           r"(%[\w.\-]+)", line)
+        for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+            named += [c.strip() for c in group.split(",")]
+        return named
+
+    stack = [c for lines in comps.values() for line in lines
+             if " while(" in line for c in callees(line)]
+    looped = set()
+    while stack:
+        c = stack.pop()
+        if c not in looped and c in comps:
+            looped.add(c)
+            stack += [k for line in comps[c] for k in callees(line)]
+
+    def plain(layout):
+        return re.sub(r"S\(\d+\)", "", layout)
+
+    found = []
+    for c in looped:
+        for line in comps[c]:
+            m = re.match(r"\s*(%[\w.\-]+) = (\w+\[[\d,]*\])(\{[^}]*\})?"
+                         r" copy\((%[\w.\-]+)\)", line)
+            if m and m.group(2) in shapes and m.group(4) in defs:
+                src, dst = defs[m.group(4)][1], m.group(3) or ""
+                if plain(src) != plain(dst):
+                    found.append(f"{m.group(1)}: {src} -> {dst}")
+    return found
+
+
 def test_rounds_kernel_compiles(one_chip):
-    secs, mem = compile_for(one_chip, *rounds_call())
+    secs, compiled = compile_for(one_chip, *rounds_call())
+    mem = compiled.memory_analysis()
     assert mem is None or mem.temp_size_in_bytes < 16 << 30, secs
 
 
@@ -172,6 +222,22 @@ def test_fused_chain_compiles(one_chip):
     assert {"_fuse_alloc", "_fuse_preempt", "_fuse_reclaim"} <= set(calls)
     for name in sorted(calls):
         compile_for(one_chip, *calls[name])
+
+
+def test_fused_op_log_is_never_relaid_out(one_chip):
+    """The preempt and reclaim machines carry their op log without a
+    relayout inside any loop. Carried as a [log_rows, 3] array, the v5e
+    compile copied it between a row-major layout, whose minor 3 pads to
+    128 lanes, and a compact one at every machine step; a flat log has
+    one tiling."""
+    calls = fused_calls()
+    for name in ("_fuse_preempt", "_fuse_reclaim"):
+        rows = calls[name][1][0].log_rows
+        _, compiled = compile_for(one_chip, *calls[name])
+        hlo = compiled.as_text()
+        assert f"s32[{3 * rows}]" in hlo, (name, rows)
+        found = loop_relayouts(hlo, {f"s32[{3 * rows}]", f"s32[{rows},3]"})
+        assert found == [], (name, found)
 
 
 @pytest.mark.parametrize("kind", ["preempt", "reclaim"])

@@ -345,8 +345,8 @@ def _seed(name: str, pad_aware: bool) -> AbsVal:
                       (f"{name}: node-axis payload (rows past node_real "
                        f"are pad)",))
     if name == "log":
-        return AbsVal(-INF, INF, "i32", CLEAN, _LOG, 3, None,
-                      (f"{name}: packed op log [LOG_ROWS, 3]",))
+        return AbsVal(-INF, INF, "i32", CLEAN, 3 * _LOG, None, None,
+                      (f"{name}: flat op log [3 * LOG_ROWS]",))
     # unknown: TOP and CLEAN — the analysis under-approximates, so an
     # unrecognized name means silence, never noise (see module docstring)
     return AbsVal(chain=(f"{name}: unknown (top)",))

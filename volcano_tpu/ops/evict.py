@@ -71,7 +71,7 @@ from volcano_tpu.utils import trace
 
 logger = logging.getLogger(__name__)
 
-# op log kinds (packed int32 rows [kind, a, b])
+# op log kinds (flat int32 log of row-major [kind, a, b] rows)
 # OP_EVICT carries (node, slot) as separate columns: the flat
 # node * V + slot encoding overflows int32 once NODES_PAD * V_WIDTH
 # crosses 2^31 (cfg7 x victim-bucket extents reach ~6.6e9)
@@ -104,6 +104,7 @@ class EvictSpec(NamedTuple):
     use_gang_pipelined: bool
     use_prop_overused: bool = False
     use_prop_queue_order: bool = False
+    log_rows: int = 0            # op-log capacity, off the bucket ladder
 
 
 class _Unsupported(Exception):
@@ -425,14 +426,27 @@ def _victim_masks(spec: EvictSpec, enc, st, claimees, claimer_job,
 # ---------------------------------------------------------------------------
 
 
+def _log0(spec: EvictSpec) -> dict:
+    """The empty op log, built on the device: one flat int32 buffer of
+    ``log_rows`` row-major [kind, a, b] rows, the packed result's own
+    layout. Not a [rows, 3] array: on the TPU its minor dim of 3 pads to
+    128 lanes, and the compiler relaid such a log out at every machine
+    step."""
+    return dict(log=jnp.zeros(3 * spec.log_rows, jnp.int32),
+                log_len=jnp.int32(0))
+
+
 def _log_append(st, kind, a, b, active):
-    i = jnp.minimum(st["log_len"], st["log"].shape[0] - 1)
+    rows = st["log"].shape[0] // 3
+    at = 3 * jnp.minimum(st["log_len"], rows - 1)
     row = jnp.stack([jnp.int32(kind), a.astype(jnp.int32),
                      b.astype(jnp.int32)])
+    old = lax.dynamic_slice(st["log"], (at,), (3,))
     st = dict(st)
-    st["log"] = st["log"].at[i].set(jnp.where(active, row, st["log"][i]))
+    st["log"] = lax.dynamic_update_slice(
+        st["log"], jnp.where(active, row, old), (at,))
     st["log_len"] = st["log_len"] + active.astype(jnp.int32)
-    st["fail"] = st["fail"] | (st["log_len"] >= st["log"].shape[0])
+    st["fail"] = st["fail"] | (st["log_len"] >= rows)
     return st
 
 
@@ -509,9 +523,7 @@ def _discard(enc, st, stmt_start):
 
     def body(st):
         i = st["log_len"] - 1
-        kind = st["log"][i, 0]
-        a = st["log"][i, 1]
-        b = st["log"][i, 2]
+        kind, a, b = lax.dynamic_slice(st["log"], (3 * i,), (3,))
         is_e = kind == OP_EVICT
         is_p = kind == OP_PIPELINE
         # evict inverse (un-evict: alive back, ready/job/queue re-add)
@@ -671,7 +683,7 @@ def _preempt_walk(spec: EvictSpec, enc, st, t, j, intra):
 # ---------------------------------------------------------------------------
 
 
-def preempt_state0(enc: dict) -> dict:
+def preempt_state0(spec: EvictSpec, enc: dict) -> dict:
     """Initial preempt machine state from the encoded action arrays. The
     session-fused driver overrides the dynamic slices (used/cnt/ready/
     alloc/heaps/p_done) with carry-bridged values; the per-action entry
@@ -683,7 +695,7 @@ def preempt_state0(enc: dict) -> dict:
         job_alloc=enc["job_alloc0"], queue_alloc=enc["queue_alloc0"],
         ptr=enc["job_task_start"],
         heap=enc["heap0"], hsize=enc["hsize0"],
-        log=enc["log0"], log_len=jnp.int32(0),
+        **_log0(spec),
         rr=enc["rr0"].astype(jnp.int32),
         p_done=jnp.zeros(enc["p_req"].shape[0], bool),
         mode=jnp.int32(M_QUEUE), qi=jnp.int32(0), cur_job=jnp.int32(0),
@@ -697,11 +709,11 @@ def preempt_state0(enc: dict) -> dict:
 
 def evict_tail(st: dict):
     """Pack the machine's final state into the single-fetch int32 result:
-    flattened op log + [log_len, rr, victims, attempts, fail, underflow]."""
+    flat op log + [log_len, rr, victims, attempts, fail, underflow]."""
     tail = jnp.stack([
         st["log_len"], st["rr"], st["victims"], st["attempts"],
         st["fail"].astype(jnp.int32), st["underflow"].astype(jnp.int32)])
-    return jnp.concatenate([st["log"].reshape(-1), tail])
+    return jnp.concatenate([st["log"], tail])
 
 
 def preempt_machine(spec: EvictSpec, enc: dict, st: dict) -> dict:
@@ -858,7 +870,7 @@ def preempt_machine(spec: EvictSpec, enc: dict, st: dict) -> dict:
 def solve_preempt(spec: EvictSpec, enc: dict):
     """Per-action packed preempt entry: host-encoded initial state, packed
     single-fetch result (evict_tail)."""
-    return evict_tail(preempt_machine(spec, enc, preempt_state0(enc)))
+    return evict_tail(preempt_machine(spec, enc, preempt_state0(spec, enc)))
 
 
 # ---------------------------------------------------------------------------
@@ -950,7 +962,7 @@ def _reclaim_walk(spec: EvictSpec, enc, st, t, j):
     return out["assigned"], st
 
 
-def reclaim_state0(enc: dict) -> dict:
+def reclaim_state0(spec: EvictSpec, enc: dict) -> dict:
     """Initial reclaim machine state (fused driver overrides the dynamic
     slices, exactly like preempt_state0)."""
     return dict(
@@ -961,7 +973,7 @@ def reclaim_state0(enc: dict) -> dict:
         ptr=enc["job_task_start"],
         heap=enc["heap0"], hsize=enc["hsize0"],
         qheap=enc["qheap0"], qhsize=enc["qhsize0"],
-        log=enc["log0"], log_len=jnp.int32(0),
+        **_log0(spec),
         rr=enc["rr0"].astype(jnp.int32),
         p_done=jnp.zeros(enc["p_req"].shape[0], bool),
         victims=jnp.int32(0), attempts=jnp.int32(0),
@@ -1040,7 +1052,7 @@ def reclaim_machine(spec: EvictSpec, enc: dict, st: dict) -> dict:
 @functools.partial(jax.jit, static_argnames=("spec",))
 def solve_reclaim(spec: EvictSpec, enc: dict):
     """Per-action packed reclaim entry (evict_tail result)."""
-    return evict_tail(reclaim_machine(spec, enc, reclaim_state0(enc)))
+    return evict_tail(reclaim_machine(spec, enc, reclaim_state0(spec, enc)))
 
 
 # ---------------------------------------------------------------------------
@@ -1730,8 +1742,7 @@ class _EvictPlan:
         # live log ≤ committed evicts (each victim commits at most once) +
         # committed pipelines + commit markers (≤ job pops + phase-2 tasks)
         # + one open statement's ops; overflow just fails to the old path
-        self.log_rows = _bucket(2 * total_victims + 4 * tb + jb + 64)
-        arrays["log0"] = np.zeros((self.log_rows, 3), np.int32)
+        log_rows = _bucket(2 * total_victims + 4 * tb + jb + 64)
 
         self.arrays = arrays
         self.p_tasks = p_tasks
@@ -1748,6 +1759,7 @@ class _EvictPlan:
             use_gang_pipelined="gang" in pipelined_names,
             use_prop_overused="proportion" in ssn.overused_fns,
             use_prop_queue_order="proportion" in queue_order,
+            log_rows=log_rows,
         )
         if fused and kind == "preempt":
             self.reclaim_spec = self.spec._replace(
@@ -1807,7 +1819,7 @@ class _EvictPlan:
         return ok
 
     def _consume(self, out, kind, prof, key, stage) -> bool:
-        lr = self.log_rows
+        lr = self.spec.log_rows
         tail = out[lr * 3:]
         log_len, rr, victims, attempts, fail, underflow = (
             int(tail[0]), int(tail[1]), int(tail[2]), int(tail[3]),

@@ -175,7 +175,7 @@ def _fuse_preempt(spec, layout, bufs, carry, sizes):
     from volcano_tpu.ops import evict as evict_mod
     from volcano_tpu.ops import rounds as rounds_mod
 
-    qp, jcap, pb, log_rows = sizes
+    qp, jcap, pb = sizes
     enc = rounds_mod.unpack_layout(layout, bufs)
     skip = carry["skip"]
     p_next = evict_mod._live_next(~skip)
@@ -221,7 +221,7 @@ def _fuse_preempt(spec, layout, bufs, carry, sizes):
         job_alloc=job_alloc, queue_alloc=queue_alloc,
         ptr=enc["job_task_start"],
         heap=heap, hsize=hsize,
-        log=jnp.zeros((log_rows, 3), jnp.int32), log_len=jnp.int32(0),
+        **evict_mod._log0(spec),
         rr=enc["rr0"].astype(jnp.int32),
         p_done=skip,
         mode=jnp.int32(evict_mod.M_QUEUE), qi=jnp.int32(0),
@@ -256,7 +256,7 @@ def _fuse_reclaim(spec, layout, bufs, carry, sizes, use_gang_valid):
     from volcano_tpu.ops import evict as evict_mod
     from volcano_tpu.ops import rounds as rounds_mod
 
-    qb, jcap, qh, log_rows = sizes
+    qb, jcap, qh = sizes
     enc = rounds_mod.unpack_layout(layout, bufs)
     skip = carry["skip"]
     p_next = evict_mod._live_next(~skip)
@@ -317,7 +317,7 @@ def _fuse_reclaim(spec, layout, bufs, carry, sizes, use_gang_valid):
         ptr=enc["job_task_start"],
         heap=heap, hsize=hsize,
         qheap=qheap, qhsize=qhsize,
-        log=jnp.zeros((log_rows, 3), jnp.int32), log_len=jnp.int32(0),
+        **evict_mod._log0(spec),
         rr=jnp.int32(0),
         p_done=skip,
         victims=jnp.int32(0), attempts=jnp.int32(0),
@@ -543,8 +543,8 @@ def _run_fused(ssn, chain, prep, plan, bf) -> dict:
     # jit-static stage sizes, all off the plan's bucket ladder (VT002)
     fs = plan.fuse_sizes
     sizes_a = (fs["n"], fs["jb"], fs["qb"], fs["tb"])
-    sizes_p = (fs["qp"], fs["jcap"], fs["ju"], plan.log_rows)
-    sizes_r = (fs["qb"], fs["jcap"], fs["qh"], plan.log_rows)
+    sizes_p = (fs["qp"], fs["jcap"], fs["ju"])
+    sizes_r = (fs["qb"], fs["jcap"], fs["qh"])
     use_gang_valid = "gang" in ssn.job_valid_fns
 
     # --- dispatch the whole chain eagerly (device-to-device carries) ------

@@ -2,7 +2,7 @@
 
 Pins JAX to the CPU with 8 virtual devices so multi-chip sharding paths can
 be exercised without TPU hardware (tests never run on the chip;
-chip_smoke.py does), enables float64 so device parity tests match the host
+benchmark/run.py does), enables float64 so device parity tests match the host
 oracle's arithmetic bit-for-bit (the chip path runs float32; see
 ops/solver.py and tests/test_tpu_compile.py), and enables panic-on-assert so
 resource accounting violations fail tests loudly.

@@ -1,8 +1,8 @@
 """Shared test scaffolding: build a cache with fakes, open sessions with
 explicit tiers (the allocate_test.go:39-223 harness shape).
 
-The builders live in volcano_tpu.bench.clusters so the bench rig and the
-test harness can never diverge; this module re-exports them plus the
+The builders live in volcano_tpu.bench.clusters, which the benchmark's
+configuration check also reads; this module re-exports them plus the
 session lifecycle helpers.
 """
 

@@ -21,7 +21,6 @@ Four seams:
 from __future__ import annotations
 
 import json
-import subprocess
 import sys
 
 import pytest
@@ -306,23 +305,65 @@ class TestFrontDoorMetrics:
             metrics.reset()
 
 
-class TestFanoutBenchCli:
-    def test_bench_fanout_tail(self, tmp_path):
-        """`bench.py --fanout N` — the standing 10k-watcher column at a
-        smoke size: bounded per-watcher memory (cursor+counters only)
-        and a recorded p99 delivery latency."""
-        out = subprocess.run(
-            [sys.executable, "bench.py", "--fanout", "400"],
-            capture_output=True, text=True, timeout=240,
-            cwd="/root/repo")
-        assert out.returncode == 0, out.stderr[-2000:]
-        tail = json.loads(out.stdout.strip().splitlines()[-1])
-        fanout = tail["summary"]["watch_fanout"]
-        assert fanout["watchers"] == 400
-        assert fanout["deliveries"] > 0
-        assert fanout["fanout_p99_ms"] >= 0.0
-        # the O(events + watchers) proof: per-watcher state is a few
-        # hundred bytes (cursor + counters), no queues, no copies
-        assert fanout["per_watcher_state_bytes"] < 4096
-        assert fanout["journal_peak_occupancy"] \
-            <= fanout["journal_hard_cap"]
+def _pod(i: int):
+    from volcano_tpu.scheduler.util.test_utils import build_pod
+
+    pod = build_pod("fan", f"pod-{i:06d}", "", objects.POD_PHASE_PENDING,
+                    {"cpu": "100m", "memory": "64Mi"}, "")
+    pod.metadata.ensure_identity()
+    return pod
+
+
+class TestWatchFanout:
+    @pytest.mark.parametrize("watchers", [1, 400])
+    def test_fanout_bounded(self, watchers):
+        """Many watchers over ONE shared journal, polled in lockstep with
+        pod churn, with a laggard tail that polls every eighth batch:
+        every watcher registers and is delivered to, per-watcher state is
+        a cursor and counters (no queues, no copies), and the laggards'
+        pins never hold the ring past its hard cap."""
+        import copy
+
+        from volcano_tpu.store.flowcontrol import WatchFanout
+        from volcano_tpu.store.gateway import _WatchJournal
+
+        store = Store()
+        journal = _WatchJournal(store, "Pod", cap=256)
+        fanout = WatchFanout(journal, pin_factor=4, ladder=DegradeLadder())
+        live = [_pod(i) for i in range(64)]
+        for pod in live:
+            store.create(pod)
+        cursors = [0] * watchers
+        delivered = 0
+        for batch in range(12):
+            for k in range(48):
+                if k % 7 == 0:
+                    live.append(_pod(len(live)))
+                    store.create(live[-1])
+                    continue
+                cur = store.try_get("Pod", "fan",
+                                    live[(batch * 48 + k) % len(live)]
+                                    .metadata.name)
+                upd = copy.deepcopy(cur)
+                upd.metadata.annotations["b"] = str(batch)
+                store.update(upd)
+            for i in range(watchers):
+                if i % 50 == 49 and batch % 8:
+                    continue  # the laggard tail
+                events, cursors[i], reset = fanout.poll_for(
+                    f"w{i:05d}", cursors[i], 0.0,
+                    cls="interactive" if i % 3 == 0 else "batch")
+                if not reset:
+                    delivered += len(events)
+        stats = fanout.watch_stats()
+        assert len(fanout.watchers) == watchers
+        assert sum(c["watchers"] for c in stats["classes"].values()) \
+            == watchers
+        assert delivered > 0
+        state_bytes = max(
+            sys.getsizeof(ws) + sum(sys.getsizeof(getattr(ws, slot))
+                                    for slot in type(ws).__slots__)
+            for ws in fanout.watchers.values())
+        assert state_bytes < 4096
+        assert stats["journal"]["peak_occupancy"] \
+            <= stats["journal"]["hard_cap"]

@@ -1,5 +1,6 @@
-"""Benchmark rig: reproducible synthetic clusters for the five BASELINE.json
-configs and session-latency measurement helpers."""
+"""Reproducible synthetic clusters for the BASELINE.json configs: the tests
+build from them, and benchmark/tests/test_configs.py checks the benchmark's
+own generator against them."""
 
 from volcano_tpu.bench.clusters import CONFIGS, build_config, make_tiers
 

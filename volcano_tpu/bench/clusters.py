@@ -337,46 +337,3 @@ def build_config(cfg: int, scale: float = 1.0) -> tuple:
     serial_tiers = make_tiers(*bc.tiers)
     tpu_tiers = make_tiers(["tpuscore"], *bc.tiers)
     return cache, serial_tiers, tpu_tiers, bc.actions, n_tasks
-
-
-def build_scenario(ref: str, scale: float = 1.0) -> tuple:
-    """The ``--scenario`` twin of build_config: source the cluster
-    snapshot (nodes, queues, initial pending gangs) and the policy from a
-    sim scenario file (volcano_tpu/sim/scenarios/), so bench and sim
-    share ONE cluster-shape source. Same return contract as
-    build_config; the scenario's scheduler.conf supplies tiers+actions
-    (tpuscore stripped for the serial side, prepended for the TPU side
-    when absent)."""
-    from volcano_tpu.scheduler import conf as conf_mod
-    from volcano_tpu.scheduler.scheduler import (
-        DEFAULT_SCHEDULER_CONF,
-        TPU_SCHEDULER_CONF,
-        load_scheduler_conf,
-    )
-    from volcano_tpu.sim.clock import RngStreams
-    from volcano_tpu.sim.workload import (
-        load_scenario,
-        populate_cache,
-        scale_scenario,
-    )
-
-    cfg = scale_scenario(load_scenario(ref), scale)
-    conf_ref = cfg["scheduler"]["conf"]
-    conf_str = {"tpu": TPU_SCHEDULER_CONF,
-                "default": DEFAULT_SCHEDULER_CONF}.get(conf_ref, conf_ref)
-    actions, tiers = load_scheduler_conf(conf_str)
-    serial_tiers = []
-    for tier in tiers:
-        plugins = [p for p in tier.plugins if p.name != "tpuscore"]
-        if plugins:
-            serial_tiers.append(conf_mod.Tier(plugins=plugins))
-    if any(p.name == "tpuscore" for t in tiers for p in t.plugins):
-        tpu_tiers = tiers
-    else:
-        tpu_tiers = make_tiers(["tpuscore"]) + serial_tiers
-
-    cache = make_cache()
-    n_tasks = populate_cache(
-        cache, cfg, RngStreams(0).stream("workload"))
-    return (cache, serial_tiers, tpu_tiers,
-            tuple(a.name() for a in actions), n_tasks)

@@ -4,7 +4,7 @@ sub-10 ms run-once path.
 Arrivals are event-driven: the SchedulerCache's pod/podgroup handlers
 notify the lane (cache.set_arrival_listener) as they mirror the watch
 stream, the lane enqueues the owning job and sets its wake event, and the
-scheduler loop (or the simulator's express slice, or bench --express)
+scheduler loop (or the simulator's express slice)
 services the queue between full sessions. The fast path is:
 
     drain -> classify (cache lock) -> refresh live axis (dirty rows only)

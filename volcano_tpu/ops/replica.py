@@ -114,10 +114,6 @@ def enabled() -> bool:
     return os.environ.get("VOLCANO_TPU_REPLICA", "1") != "0"
 
 
-def adopt_enabled() -> bool:
-    return os.environ.get("VOLCANO_TPU_REPLICA_ADOPT", "1") != "0"
-
-
 def get(cache, create: bool = True) -> Optional["DeviceReplica"]:
     """The cache's standing replica (one per SchedulerCache), created on
     first use. None when disabled or the cache has no snapshot keeper."""
@@ -531,7 +527,7 @@ class DeviceReplica:
         discarded. Shapes/dtypes/sharding must match the standing
         buffers; anything else is ignored (the next serve's mirror diff
         re-scatters honestly)."""
-        if not adopt_enabled() or not self.dev:
+        if not self.dev:
             return
         taken = 0
         for name, buf in buffers.items():

@@ -1,10 +1,10 @@
 """Mesh sharding utilities: per-shard staging of the node axis.
 
 The node-axis shard of the rounds kernel is bit-identical to the
-single-device solve (tests/test_mesh_shard.py; chip_smoke.py --chips 4 on
-chips), but the surrounding stages used to de-shard the axis — the encoder staged full-
-width matrices through one `jax.device_put` stream per array (no device
-cache at all on the mesh path), and the evict victim folds ran unsharded.
+single-device solve (tests/test_mesh_shard.py), but the surrounding
+stages used to de-shard the axis — the encoder staged full-width
+matrices through one `jax.device_put` stream per array (no device cache
+at all on the mesh path), and the evict victim folds ran unsharded.
 This module is the shared staging layer that keeps the axis sharded
 end-to-end:
 
@@ -28,13 +28,7 @@ end-to-end:
 - **replicated staging** (`replicated_sharding`): the packed non-node
   buffers ride the existing grouped transfer but must commit to the SAME
   mesh (a single-device buffer cannot enter a jit call alongside a sharded
-  array), so the solver/evict `_stage` caches key on the mesh identity too;
-- **per-device stage probes** (`probe_per_device_stage_ms`): the bench
-  mesh curve's measured per-shard critical path — the CPU proxy cannot run
-  8 shards truly in parallel, so the curve times ONE shard's slice of the
-  sharded stages (the rounds score refresh and the evict victim folds) at
-  per-shard width N/d; on the real mesh shards execute concurrently, so
-  the per-shard wall IS the stage wall up to the cross-shard reduce.
+  array), so the solver/evict `_stage` caches key on the mesh identity too.
 
 The mesh axis is always the node axis (axis name "nodes", the existing
 `Mesh(devices, ("nodes",))` convention); cross-shard communication happens
@@ -45,7 +39,6 @@ bit-identity under the shard.
 
 from __future__ import annotations
 
-import functools
 from typing import Dict, Optional
 
 import jax
@@ -60,7 +53,7 @@ _SHARD_CACHE: Dict[tuple, tuple] = {}
 
 
 def clear_cache() -> None:
-    """Drop the per-shard device cache (tests / bench mesh sweeps)."""
+    """Drop the per-shard device cache (tests)."""
     _SHARD_CACHE.clear()
 
 
@@ -177,123 +170,3 @@ def stage_node_arrays(arrays: Dict[str, np.ndarray],
             profile.get("h2d_shard_cached", 0) + hits
         profile["h2d_bytes"] = profile.get("h2d_bytes", 0) + put_bytes
     return staged
-
-
-# ---------------------------------------------------------------------------
-# bench mesh-curve probes: one shard's slice of the sharded stages
-# ---------------------------------------------------------------------------
-
-
-_PROBE_REPS = 16
-
-
-@functools.partial(jax.jit, static_argnames=("spec",))
-def _probe_refresh(spec, enc):
-    """_PROBE_REPS full score refreshes (the rounds kernel's per-round
-    fold) over a per-shard node slice — the dominant sharded stage of the
-    allocate solve. The idle perturbation varies per iteration so XLA
-    cannot hoist the loop-invariant refresh out of the rep loop (a session
-    runs many rounds; the rep loop stands in for them)."""
-    import jax.numpy as jnp
-    from jax import lax
-
-    from volcano_tpu.ops import rounds as rounds_mod
-
-    occ = enc.get("excl_occ0") if spec.use_exclusion else None
-
-    def body(i, acc):
-        idle = enc["node_idle"] * (1.0 + i * 1e-12)
-        sc = rounds_mod._refresh_scores(
-            spec, enc, idle, enc["node_used"], enc["node_cnt"], occ)
-        return acc + sc[0, 0]
-
-    return lax.fori_loop(0, _PROBE_REPS, body,
-                         jnp.asarray(0.0, enc["node_idle"].dtype))
-
-
-@jax.jit
-def _probe_evict_fold(vic_req, vic_queue, vic_samequeue, queue_alloc,
-                      queue_deserved, eps):
-    """_PROBE_REPS proportion deserved-floor victim walks
-    (ops/evict._prop_verdict twin) over a per-shard [N/d, V] victim slice
-    — the dominant sharded stage of the evict machines. Same
-    per-iteration perturbation trick as _probe_refresh."""
-    import jax.numpy as jnp
-    from jax import lax
-
-    v_width = vic_queue.shape[1]
-    des = queue_deserved[vic_queue]
-    claim = jnp.ones(vic_queue.shape, bool)
-
-    def one_walk(qcur0):
-        def body(v, carry):
-            qcur, out = carry
-            req = vic_req[:, v]
-            cur = qcur[:, v]
-            do = claim[:, v] & ~jnp.all(cur < req, axis=-1)
-            fits = jnp.all(
-                (des[:, v] < cur - req)
-                | (jnp.abs(des[:, v] - (cur - req)) < eps), axis=-1)
-            out = out.at[:, v].set(do & fits)
-            upd = (do[:, None] & vic_samequeue[:, v, :])[..., None]
-            qcur = jnp.where(upd, qcur - req[:, None, :], qcur)
-            return qcur, out
-
-        return lax.fori_loop(
-            0, v_width, body, (qcur0, jnp.zeros(vic_queue.shape, bool)))[1]
-
-    def rep(i, acc):
-        qcur0 = queue_alloc[vic_queue] * (1.0 + i * 1e-12)
-        return acc + jnp.sum(one_walk(qcur0).astype(jnp.int32))
-
-    return lax.fori_loop(0, _PROBE_REPS, rep, jnp.int32(0))
-
-
-def probe_per_device_stage_ms(spec, arrays: Dict[str, np.ndarray],
-                              node_axis: Dict[str, int], shards: int,
-                              vic_width: int = 8, iters: int = 3) -> float:
-    """Measured wall of ONE shard's slice of the sharded session stages at
-    per-shard width N/shards: the rounds score refresh over the real
-    encoded class/node arrays, plus a proportion victim fold at the same
-    node slice. On the real mesh the shards run concurrently, so this
-    per-shard wall is the stage's critical path (up to the cross-shard
-    verdict reduce); on the CPU proxy it is the honest measured stand-in
-    for a parallelism the host cannot provide. Returns the median wall in
-    ms across ``iters`` timed repetitions (first call pays the compile,
-    excluded)."""
-    import time
-
-    n_total = int(np.asarray(arrays["node_idle"]).shape[0])
-    width = per_shard(pad_axis_multiple(
-        np.zeros(n_total, np.int8), 0, shards).shape[0], shards)
-    enc = {}
-    for k, v in sorted(arrays.items()):
-        v = np.asarray(v)
-        axis = node_axis.get(k)
-        if axis is None:
-            enc[k] = v
-            continue
-        v = pad_axis_multiple(v, axis, shards)
-        idx = [slice(None)] * v.ndim
-        idx[axis] = slice(0, width)
-        enc[k] = np.ascontiguousarray(v[tuple(idx)])
-    rng = np.random.default_rng(7)
-    fdt = np.asarray(arrays["node_idle"]).dtype
-    vic_req = rng.uniform(100.0, 4000.0, (width, vic_width, 2)).astype(fdt)
-    vic_queue = rng.integers(0, 4, (width, vic_width)).astype(np.int32)
-    samequeue = vic_queue[:, :, None] == vic_queue[:, None, :]
-    queue_alloc = rng.uniform(1e4, 1e6, (4, 2)).astype(fdt)
-    queue_deserved = rng.uniform(1e4, 1e6, (4, 2)).astype(fdt)
-    eps = np.asarray([0.01, 0.01], fdt)
-
-    def once():
-        t0 = time.perf_counter()
-        r = _probe_refresh(spec, enc)
-        f = _probe_evict_fold(vic_req, vic_queue, samequeue, queue_alloc,
-                              queue_deserved, eps)
-        jax.block_until_ready((r, f))
-        return (time.perf_counter() - t0) * 1e3
-
-    once()  # compile, excluded from the timed reps
-    walls = sorted(once() for _ in range(max(iters, 1)))
-    return round(walls[len(walls) // 2], 3)

@@ -263,7 +263,7 @@ def _stage(bufs: Dict[str, np.ndarray],
     single-device array cannot enter a jit call alongside mesh-sharded node
     buffers), and the cache entries carry the mesh identity — a buffer
     staged for one mesh shape is never handed to a program compiled for
-    another (the bench mesh sweep walks 1/2/4/8 devices in one process).
+    another (tests walk several mesh sizes in one process).
 
     When `profile` is given, records the H2D hop budget: how many buffers
     crossed the link (`h2d_puts`) vs were device-resident (`h2d_cached`),

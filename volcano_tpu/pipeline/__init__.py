@@ -8,11 +8,10 @@ sealed at dispatch and re-checked before apply guarantees an invalidated
 speculative stage is never applied (docs/DESIGN.md §16).
 
 ``VOLCANO_TPU_PIPELINE=0`` keeps the serial loop (the byte-for-byte
-oracle); ``VOLCANO_TPU_PIPELINE_SPEC=0`` keeps the pipelined loop but
+oracle); ``PipelineDriver(spec=False)`` keeps the pipelined loop but
 never speculates (double-buffer-only mode, the parity fuzz's midpoint).
 """
 
-from volcano_tpu.pipeline.driver import PipelineDriver, pipeline_enabled, \
-    speculation_enabled
+from volcano_tpu.pipeline.driver import PipelineDriver, pipeline_enabled
 
-__all__ = ["PipelineDriver", "pipeline_enabled", "speculation_enabled"]
+__all__ = ["PipelineDriver", "pipeline_enabled"]

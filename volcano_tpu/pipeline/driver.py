@@ -44,7 +44,7 @@ A discarded stage is never fetched into session state and the cycle
 re-runs non-speculatively on fresh state — which is exactly the serial
 order, so the serial loop (``VOLCANO_TPU_PIPELINE=0``) stays the
 byte-for-byte oracle whether speculation is on, off
-(``VOLCANO_TPU_PIPELINE_SPEC=0``), held, or discarded. A read-set
+(``spec=False``), held, or discarded. A read-set
 commit linearizes the stage AT ITS SEAL POINT: the disjoint deltas that
 arrived mid-solve are consumed by the NEXT cycle's snapshot, exactly as
 if they had arrived one cycle later — legal because, being disjoint,
@@ -107,12 +107,6 @@ def pipeline_enabled() -> bool:
     return os.environ.get("VOLCANO_TPU_PIPELINE", "1") != "0"
 
 
-def speculation_enabled() -> bool:
-    """VOLCANO_TPU_PIPELINE_SPEC=0 keeps the pipelined loop but never
-    dispatches ahead (double-buffer-only mode)."""
-    return os.environ.get("VOLCANO_TPU_PIPELINE_SPEC", "1") != "0"
-
-
 def readset_enabled() -> bool:
     """VOLCANO_TPU_READSET=0 restores whole-fingerprint invalidation:
     any movement discards the stage, read set unconsulted."""
@@ -170,7 +164,7 @@ class PipelineDriver:
     _RATE_WINDOW = 32
 
     def __init__(self, cache, policy_fn: Callable[[], Tuple[list, list]],
-                 degrade=None, spec: Optional[bool] = None,
+                 degrade=None, spec: bool = True,
                  intake: Optional[Callable[[], None]] = None):
         self.cache = cache
         self.policy_fn = policy_fn
@@ -179,12 +173,14 @@ class PipelineDriver:
         # and a driver built before the reset must not gate on the stale
         # one
         self._degrade = degrade
-        self.spec = speculation_enabled() if spec is None else spec
+        # spec=False keeps the pipelined loop but never dispatches ahead
+        # (double-buffer-only mode)
+        self.spec = spec
         # intake: drained AFTER the cycle commits and BEFORE the next
         # cycle's snapshot seals — the watch-ingest quantization point.
-        # A driver (bench --pipeline, an embedder pumping a delta queue)
-        # that funnels arrivals through it makes them visible to the very
-        # next speculative snapshot instead of invalidating it mid-flight;
+        # An embedder pumping a delta queue that funnels arrivals through
+        # it makes them visible to the very next speculative snapshot
+        # instead of invalidating it mid-flight;
         # deltas that bypass it (live watch events, express commits) are
         # still caught by the fingerprint and discard the stage.
         self.intake = intake

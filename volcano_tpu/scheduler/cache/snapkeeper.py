@@ -44,13 +44,12 @@ structure the same way: rows are refreshed in place for re-cloned /
 session-mutated nodes and the whole axis is recaptured only when the
 ready-node membership changes.
 
-``VOLCANO_TPU_WHOLESALE_SNAPSHOT=1`` disables the keeper (every snapshot
-is a full rebuild — the round-5 behavior and the parity oracle).
+Setting ``enabled = False`` makes every snapshot a full rebuild (the
+round-5 behavior and the parity oracle).
 """
 
 from __future__ import annotations
 
-import os
 from typing import Dict, Set
 
 import numpy as np
@@ -100,7 +99,7 @@ class _SnapshotBuffer:
 
 class SnapshotKeeper:
     def __init__(self):
-        self.enabled = not os.environ.get("VOLCANO_TPU_WHOLESALE_SNAPSHOT")
+        self.enabled = True
         self.jobs: Dict[str, object] = {}    # uid -> clone in the live snap
         self.nodes: Dict[str, object] = {}   # name -> clone (ready only)
         self.job_vers: Dict[str, int] = {}   # uid -> in-sync _status_version

@@ -159,7 +159,6 @@ class Scheduler:
         # session gate share one ladder; embedders report remote-store
         # health through it too
         self.degrade = degrade_mod.default_ladder()
-        self.last_profile: dict = {}
         # sessions run so far: the step number of the next vt.session span
         self.sessions = 0
 
@@ -360,10 +359,5 @@ class Scheduler:
                 # action records its own duration (framework.action_span)
                 run_actions(ssn, self.actions)
             finally:
-                tpu = ssn.plugins.get("tpuscore")
-                # the device path's record of this cycle (mode, fallbacks,
-                # timings), read by chip_smoke.py
-                self.last_profile = dict(tpu.profile) if tpu is not None \
-                    else {}
                 close_session(ssn)
         metrics.update_e2e_duration(sp.elapsed)

@@ -1,11 +1,10 @@
 """CLI: ``python -m volcano_tpu.sim run <scenario> --seed 7``.
 
-Emits a bench-style JSON summary as the LAST stdout line (the driver-tail
-contract bench.py follows): sessions/sec, per-phase latency percentiles,
-binds/evictions, fault and audit tallies, and the replayable event-log
-hash — same scenario + same seed ⇒ identical hash. Exit code 1 when the
-auditor recorded violations (repro bundles under --repro-dir), so CI can
-gate on a chaos soak with plain shell.
+Emits a JSON summary as the LAST stdout line: sessions/sec, per-phase
+latency percentiles, binds/evictions, fault and audit tallies, and the
+replayable event-log hash — same scenario + same seed ⇒ identical hash.
+Exit code 1 when the auditor recorded violations (repro bundles under
+--repro-dir), so CI can gate on a chaos soak with plain shell.
 """
 
 from __future__ import annotations

@@ -11,11 +11,7 @@ shapes (cfg5_storm mirrors BASELINE.json cfg 5).
 
 Jobs are submitted as REAL vcjob objects through the store: the job
 controller materializes pods gated on PodGroup enqueue admission, exactly
-the production submit path — not a cache shortcut. ``populate_cache``
-is the shortcut twin for bench.py --scenario: it materializes only the
-t=0 snapshot (nodes + initial pending gangs) straight into a
-SchedulerCache, so bench and sim share ONE cluster-shape source instead
-of maintaining parallel builders.
+the production submit path — not a cache shortcut.
 """
 
 from __future__ import annotations
@@ -31,8 +27,6 @@ from volcano_tpu.api import objects
 from volcano_tpu.store.store import OverloadedError
 from volcano_tpu.scheduler.util.test_utils import (
     build_node,
-    build_pod,
-    build_pod_group,
     build_queue,
     build_resource_list_with_pods,
 )
@@ -205,8 +199,7 @@ def scale_scenario(cfg: Dict, scale: float) -> Dict:
 
 
 # ---------------------------------------------------------------------------
-# Initial-cluster object builders (shared by the sim store path and the
-# bench cache path)
+# Initial-cluster object builders
 # ---------------------------------------------------------------------------
 
 
@@ -582,36 +575,3 @@ class Workload:
                     lambda k=key: self._on_cancel(k))
             else:
                 raise ValueError(f"unknown trace op {op!r}")
-
-
-# ---------------------------------------------------------------------------
-# Bench snapshot twin (cache path)
-# ---------------------------------------------------------------------------
-
-
-def populate_cache(cache, cfg: Dict, rng) -> int:
-    """Materialize a scenario's t=0 snapshot straight into a
-    SchedulerCache (bench.py --scenario): nodes, queues, and the initial
-    pending gangs — the same shapes the sim submits through the store,
-    minus the lifecycle machinery a static latency benchmark cannot use.
-    Returns the task count."""
-    for node in iter_nodes(cfg):
-        cache.add_node(node)
-    for queue in iter_queues(cfg):
-        cache.add_queue(queue)
-    tasks = 0
-    for j in range(int(cfg["workload"]["initial_jobs"])):
-        shape = sample_job_shape(cfg, rng)
-        pg_name = f"sim-{j + 1:06d}"
-        cache.add_pod_group(build_pod_group(
-            pg_name, namespace=shape["namespace"],
-            min_member=shape["min_member"], queue=shape["queue"]))
-        requests = {"cpu": shape["cpu"], "memory": shape["mem"]}
-        if shape["gpu"]:
-            requests["nvidia.com/gpu"] = str(shape["gpu"])
-        for i in range(shape["tasks"]):
-            cache.add_pod(build_pod(
-                shape["namespace"], f"{pg_name}-w-{i}", "",
-                objects.POD_PHASE_PENDING, requests, pg_name))
-            tasks += 1
-    return tasks

@@ -122,8 +122,8 @@ class _WatchJournal:
 
         key = object_key(new if new is not None else old)
         # append-time stamp (wall monotonic, observability only — never a
-        # scheduling input): the fan-out bench derives per-watcher
-        # delivery latency from it
+        # scheduling input): per-watcher delivery latency is derived
+        # from it
         entry = {"type": etype, "key": key, "ts": _time.monotonic()}
         if new is not None:
             entry["object"] = codec.envelope(new)

@@ -11,8 +11,8 @@ is a ``vt.device.wait`` span (utils/trace.py):
   accumulated into ``overlap_s``. The wait itself is a counted sync point.
 - ``fence(x=None)`` is an explicit ``block_until_ready`` barrier — with no
   argument it drains every in-flight array registered by ``start_fetch``.
-  The bench places these around the floor probe and each warm sample so a
-  timed window can never inherit queued work from its predecessor.
+  The benchmark drains around each timed session so a timed window can
+  never inherit queued work from its predecessor.
 - ``session(profile)`` scopes the counters to one scheduler session; the
   collector lands ``tpu_sync_points`` / ``tpu_d2h_fetches`` /
   ``tpu_overlap_ms`` in the session profile.
@@ -181,5 +181,5 @@ def fence(x=None) -> None:
 
 
 def drain() -> None:
-    """fence() alias for bench call sites: drain all in-flight work."""
+    """fence() alias for benchmark call sites: drain all in-flight work."""
     fence(None)

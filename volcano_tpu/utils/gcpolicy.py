@@ -13,9 +13,9 @@ its predictable latency is the standard service recipe:
   generations every cycle, the full heap on a long stride so cyclic garbage
   still cannot accumulate unboundedly.
 
-Scheduler._loop and bench.py install this around their cycle loops; library
-users who embed a Scheduler keep whatever policy their process already has
-unless they opt in.
+Scheduler._loop and benchmark/run.py install this around their cycle
+loops; library users who embed a Scheduler keep whatever policy their
+process already has unless they opt in.
 """
 
 from __future__ import annotations

@@ -5,15 +5,15 @@ ONCE: a retrace in a warm session turns a ~100 ms cycle into a multi-second
 stall (the reference never pays anything like this — its hot loop is
 pre-compiled Go — so the rebuild must prove compilation is out of the
 steady-state path). This watcher hooks `jax.monitoring`'s duration events
-and exposes per-window deltas; bench.py records them per session, so any
-warm-path retrace shows up as `compiles > 0` in the BENCH record.
+and exposes per-window deltas, so a test that wraps a warm session in
+`assert_no_compiles` fails on any retrace.
 
 Thread-safe for the single-writer / many-reader pattern JAX uses (listener
 callbacks fire on whichever thread compiles).
 
 `enable_compile_cache` places JAX's persistent compilation cache; the entry
-points (chip_smoke.py, bench.py, ``python -m volcano_tpu.scheduler``, the
-sim CLI) call it once at start-up, never at import time.
+points (benchmark/run.py, ``python -m volcano_tpu.scheduler``, the sim
+CLI) call it once at start-up, never at import time.
 """
 
 from __future__ import annotations
@@ -88,11 +88,10 @@ class CompileWatcher:
     def assert_no_compiles(self, what: str = "warm path"):
         """Fail loudly if any XLA backend compile lands inside the block.
 
-        The enforcement twin of bench.py's per-session compile deltas
-        (``tpu_warm_compiles``): wrap a steady-state session in this and a
-        retrace fails the TEST that introduced it, instead of surfacing as
-        a multi-second stall in the next bench round. Yields the window so
-        callers can also inspect trace counts."""
+        Wrap a steady-state session in this and a retrace fails the TEST
+        that introduced it, instead of surfacing as a multi-second stall
+        in the next benchmark run. Yields the window so callers can also
+        inspect trace counts."""
         win = self.window()
         yield win
         d = win.delta()

@@ -104,10 +104,11 @@ def _all_residue(monkeypatch):
     return _session(cache, DEFAULT_TIERS, ROUNDS)
 
 
-def _scalar_overcommit():
+def _scalar_overcommit(best_effort_jobs: int = 2):
     """An overcommitted cluster with one GPU node: scalar resource
     dimensions are outside the evict kernels' envelope."""
-    cache = _overcommit_cluster(11, nodes=12)
+    cache = _overcommit_cluster(11, nodes=12,
+                                best_effort_jobs=best_effort_jobs)
     rl = build_resource_list_with_pods("8", "16Gi", pods=64)
     rl["nvidia.com/gpu"] = "4"
     cache.add_node(build_node("node-gpu", rl))
@@ -124,6 +125,15 @@ def _evict_scalar_dimension(monkeypatch):
     monkeypatch.setenv("VOLCANO_TPU_EVICT", "1")
     monkeypatch.setenv("VOLCANO_TPU_FUSE", "0")
     return _session(_scalar_overcommit(), TIER_SETS[0], ROUNDS, ACTIONS)
+
+
+def _scalar_nothing_to_backfill(monkeypatch):
+    """Scalar dimensions and no best-effort pod: backfill is trivial before
+    it reaches the envelope check, so only preempt and reclaim leave."""
+    monkeypatch.setenv("VOLCANO_TPU_EVICT", "1")
+    monkeypatch.setenv("VOLCANO_TPU_FUSE", "0")
+    return _session(_scalar_overcommit(best_effort_jobs=0), TIER_SETS[0],
+                    ROUNDS, ACTIONS)
 
 
 def _kernel_raises(monkeypatch):
@@ -181,11 +191,15 @@ EVICT_KEYS = {"evict_backfill_fallback", "evict_preempt_fallback",
      "fuse_fallback", "scalar", [4, 0]),
     (_evict_scalar_dimension, {"counters"} | EVICT_KEYS,
      "evict_preempt_fallback", "scalar", [3, 0]),
+    (_scalar_nothing_to_backfill,
+     {"counters"} | EVICT_KEYS - {"evict_backfill_fallback"},
+     "evict_preempt_fallback", "scalar", [2, 0]),
     (_kernel_raises, {"fallback", "mode", "counters"}, "fallback",
      "solve error", [0, 1]),
     (_replica_delta_raises, {"replica_rebuilds"}, None, None, None),
 ], ids=["rounds", "auto_below_gate", "all_residue", "fuse_envelope",
-        "evict_scalar", "kernel_raises", "replica_error"])
+        "evict_scalar", "scalar_no_backfill", "kernel_raises",
+        "replica_error"])
 def test_leaving_the_device_path_is_recorded(
         drive, keys, reason_key, reason, counters, monkeypatch,
         fresh_counters):

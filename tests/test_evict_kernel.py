@@ -41,7 +41,7 @@ TIER_SETS = [
 
 def _overcommit_cluster(seed: int, nodes: int = 6, running_jobs: int = 12,
                         tasks_per_job: int = 4, queues: int = 2,
-                        hi_jobs: int = 4):
+                        hi_jobs: int = 4, best_effort_jobs: int = 2):
     """Dense running fill bound round-robin with almost no idle headroom,
     pending high-priority gangs (preemptors), a starved low-weight queue
     (reclaimers), best-effort pods (backfill), and PDBs overriding some
@@ -120,7 +120,7 @@ def _overcommit_cluster(seed: int, nodes: int = 6, running_jobs: int = 12,
                 "ev", f"{pg}-t{i}", "", objects.POD_PHASE_PENDING,
                 {"cpu": "2000m", "memory": "2Gi"}, pg, priority=10))
     # best-effort pods for backfill
-    for g in range(2):
+    for g in range(best_effort_jobs):
         pg = f"be-{g:02d}"
         c.add_pod_group(build_pod_group(
             pg, namespace="ev", min_member=1, queue="q0"))

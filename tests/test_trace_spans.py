@@ -148,6 +148,9 @@ def test_span_tree_of_a_device_session(tmp_path):
             "vt.close.plugin.gang"} <= names
     # the device hop is not the serial loop's
     assert "vt.serial.predicate" not in names
+    # no zero-request task: backfill decides so before building the view
+    (plan,) = counts["vt.evict.plan"]
+    assert (plan["kind"], plan["tasks"], plan["view"]) == ("backfill", 0, 0)
 
 
 def test_device_path_span_count_does_not_grow_with_nodes():
@@ -207,6 +210,36 @@ def test_evict_stage_apply_without_the_profiler(monkeypatch):
     assert set(action_ms) == {"allocate", "backfill", "preempt", "reclaim"}
     assert prof["evict_preempt"]["apply_s"] >= 0.0
     assert "solve_s" not in prof["evict_preempt"]
+
+
+@pytest.mark.parametrize("fuse", ["0", "1"], ids=["standalone", "fused"])
+def test_evict_plan_notes_backfill_tasks_and_view(fuse, tmp_path,
+                                                  monkeypatch):
+    """With best-effort pods the backfill plan builds (or, fused, reuses
+    the preempt plan's) dense view, and its span says so."""
+    import volcano_tpu.ops.victimview as vv
+    from tests.test_evict_kernel import TIER_SETS, _overcommit_cluster
+
+    monkeypatch.setenv("VOLCANO_TPU_EVICT", "1")
+    monkeypatch.setenv("VOLCANO_TPU_FUSE", fuse)
+    monkeypatch.setattr(vv.VictimSelector, "MIN_BATCH", 1)
+    tiers = make_tiers(["tpuscore"], *TIER_SETS[0], arguments={
+        "tpuscore": {"tpuscore.mode": "rounds"}})
+
+    def run():
+        ssn = open_session(_overcommit_cluster(11), tiers)
+        try:
+            run_actions(ssn, ("allocate", "backfill", "preempt", "reclaim"))
+            return dict(ssn.plugins["tpuscore"].profile)
+        finally:
+            close_session(ssn)
+
+    run()  # compile outside the capture
+    prof, _, counts = _traced(tmp_path, run)
+    kind = "fused" if fuse == "1" else "backfill"
+    (plan,) = [c for c in counts["vt.evict.plan"] if c["kind"] == kind]
+    assert plan["tasks"] == prof["evict_backfill"]["tasks"] > 0
+    assert plan["view"] == 1
 
 
 def test_span_times_into_a_profile_key():

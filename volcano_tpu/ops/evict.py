@@ -1252,11 +1252,17 @@ def _note_fallback(prof: dict, key: str, reason: str) -> None:
     metrics.register_fallback(key)
 
 
-def _common_view(ssn, view=None):
+def _check_armed(ssn) -> None:
+    """The mode gates: the device path is not armed at all (env oracle or
+    serial conf). Cheap, so they run before anything is built."""
     if os.environ.get("VOLCANO_TPU_EVICT", "1") == "0":
         raise _Unsupported("VOLCANO_TPU_EVICT=0")
     if getattr(ssn, "batch_allocator", None) is None:
         raise _Unsupported("tpuscore off")
+
+
+def _common_view(ssn, view=None):
+    _check_armed(ssn)
     if view is None:
         from volcano_tpu.ops import preemptview
 
@@ -1330,9 +1336,11 @@ def build(ssn, kind: str):
     (the action then runs its old path — the parity oracle)."""
     prof = _profile(ssn)
     try:
-        with trace.span("evict.plan", kind=kind):
+        with trace.span("evict.plan", kind=kind) as sp:
             if kind == "backfill":
-                return _BackfillPlan(ssn)
+                plan = _BackfillPlan(ssn)
+                plan.note(sp)
+                return plan
             return _EvictPlan(ssn, kind)
     except _Unsupported as e:
         reason = str(e)
@@ -1892,6 +1900,29 @@ class _EvictPlan:
                     ssn.pipeline(self.p_tasks[a], self.node_names[b])
 
 
+def _backfill_tasks(ssn) -> List[tuple]:
+    """(job, task) of every zero-request PENDING task backfill places, in
+    ssn.jobs order and each job's PENDING order (backfill.go:41-91). A job's
+    phase and validity are checked only when it holds such a task:
+    job_valid is memoized and pure, so the order of its calls is free."""
+    from volcano_tpu.api import objects
+
+    found = []
+    for job in list(ssn.jobs.values()):
+        zero = [task for task in job.task_status_index.get(
+                    TaskStatus.PENDING, {}).values()
+                if task.init_resreq.is_empty()]
+        if not zero:
+            continue
+        if job.pod_group.status.phase == objects.PodGroupPhase.PENDING:
+            continue
+        vr = ssn.job_valid(job)
+        if vr is not None and not vr.pass_:
+            continue
+        found.extend((job, task) for task in zero)
+    return found
+
+
 class _BackfillPlan:
     """Batched backfill: the device decides every zero-request placement
     (first feasible node in name order under the evolving pod-count), the
@@ -1899,45 +1930,37 @@ class _BackfillPlan:
     FitErrors machinery — including the bounded diagnostics replay."""
 
     def __init__(self, ssn, view=None):
-        from volcano_tpu.api import objects
-
         self.ssn = ssn
+        self.view = None
+        _check_armed(ssn)
+        # triviality first: the dense view costs O(nodes + resident tasks)
+        # on the host, and a session without a zero-request task has no use
+        # for it (nor has it left the device path)
+        found = _backfill_tasks(ssn)
+        self.tasks = [task for _, task in found]
+        self.jobs_of = [job for job, _ in found]
+        self.trivial = not found
+        if self.trivial:
+            return
         view = _common_view(ssn, view)
         self.view = view
         self.mesh = getattr(
             getattr(ssn, "batch_allocator", None), "mesh", None)
-        tasks: List = []
-        jobs_of: List = []
         sig_ids: Dict[str, int] = {}
         sig_rows: List[np.ndarray] = []
         sigs: List[int] = []
-        for job in list(ssn.jobs.values()):
-            if job.pod_group.status.phase == objects.PodGroupPhase.PENDING:
-                continue
-            vr = ssn.job_valid(job)
-            if vr is not None and not vr.pass_:
-                continue
-            for task in list(job.task_status_index.get(
-                    TaskStatus.PENDING, {}).values()):
-                if not task.init_resreq.is_empty():
-                    continue
-                rows = view._rows(task)
-                if rows is None:
-                    raise _Unsupported(
-                        "backfill task with host ports / pod affinity")
-                key, mask, _ = rows
-                si = sig_ids.get(key)
-                if si is None:
-                    si = sig_ids[key] = len(sig_rows)
-                    sig_rows.append(mask)
-                sigs.append(si)
-                tasks.append(task)
-                jobs_of.append(job)
-        self.tasks = tasks
-        self.jobs_of = jobs_of
-        self.trivial = not tasks
-        if self.trivial:
-            return
+        for task in self.tasks:
+            rows = view._rows(task)
+            if rows is None:
+                raise _Unsupported(
+                    "backfill task with host ports / pod affinity")
+            key, mask, _ = rows
+            si = sig_ids.get(key)
+            if si is None:
+                si = sig_ids[key] = len(sig_rows)
+                sig_rows.append(mask)
+            sigs.append(si)
+        tasks = self.tasks
         n = view.n
         if n == 0:
             raise _Unsupported("no nodes")
@@ -1964,6 +1987,11 @@ class _BackfillPlan:
             check_pod_count=view.check_pod_count,
             use_nodeorder=False, use_binpack=False,
             use_gang_pipelined=False)
+
+    def note(self, sp) -> None:
+        """The plan's counts on its ``vt.evict.plan`` span: the zero-request
+        tasks found, and whether the dense view was built or reused."""
+        sp.note(tasks=len(self.tasks), view=int(self.view is not None))
 
     def run(self) -> bool:
         from volcano_tpu.api.unschedule_info import FitErrors, FitFailure

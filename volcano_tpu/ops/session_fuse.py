@@ -458,10 +458,12 @@ def _fused_allocate(ssn, chain: List[str]) -> Optional[dict]:
                  f"{sorted(set(ssn.job_valid_fns) - {'gang'})}"
     if reason is None:
         try:
-            with trace.span("evict.plan", kind="fused"):
+            with trace.span("evict.plan", kind="fused") as sp:
                 plan = evict_mod._EvictPlan(ssn, "preempt", fused=True)
                 bf = evict_mod._BackfillPlan(ssn, view=plan.view) \
                     if "backfill" in chain else None
+                if bf is not None:
+                    bf.note(sp)
         except evict_mod._Unsupported as e:
             reason = str(e)
         else:
